@@ -17,6 +17,7 @@ import jax
 from benchmarks import gemm_backends, gemm_ops, paper_figs, serving
 from benchmarks.common import Rows
 from benchmarks.roofline_table import roofline_rows
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> None:
@@ -37,6 +38,7 @@ def main(argv=None) -> None:
         "(benchmarks/baseline_smoke.json in CI)",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     rows = Rows()
     print("name,us_per_call,derived")

@@ -2,11 +2,13 @@
 
 RedMulE's utilization claim rests on tiles that evenly feed the CE array;
 the software mirror is that every band of the tuning layer must produce
-sublane/lane-aligned tiles inside the VMEM budget for every storage byte
-width, with the documented cross-band monotonicity (the K tile deepens as
-M thins). This module checks those properties table-by-table and by
-sweeping representative problems through the real selection functions —
-no kernel ever runs.
+tiles the TPU compiler accepts — the K tile is the last dim of the x block,
+so it is lane- as well as sublane-aligned; the decode head block is the
+whole KV-head axis or a multiple of 8 — inside the VMEM budget for every
+storage byte width, with the documented cross-band monotonicity (the K
+tile deepens as M thins). This module checks those properties
+table-by-table and by sweeping representative problems through the real
+selection functions — no kernel ever runs.
 
 Coverage is enforced structurally: :func:`discover_tables` introspects the
 tuning module for anything table-shaped (a module-level dict keyed by
@@ -29,6 +31,8 @@ _SWEEP_K = (48, 256, 4096)
 _SWEEP_DTYPES = (jnp.float8_e4m3fn, jnp.bfloat16, jnp.float32)
 # Band-boundary M values: every band interior + both sides of every seam.
 _SWEEP_M = (1, 2, 7, 8, 9, 12, 16, 17, 31, 64, 65, 96, 512, 513, 2048)
+# KV-head counts of the zoo (and a ragged 12) for the decode head block.
+_SWEEP_HKV = (1, 2, 4, 8, 12, 16, 32)
 
 # GEMM band tables: name -> (largest M the band serves, entry layout).
 # Layout "bmnk" = (bm, bn, bk) triples; "kn" = (bk, bn) pairs with bm
@@ -147,9 +151,11 @@ def validate_tuning_tables(module=tuning) -> list[TileFinding]:
             if bn % lane:
                 bad(table, itemsize,
                     f"bn={bn} not a multiple of the {lane} lane")
-            if bk % sub:
+            # A 128-lane multiple is a multiple of every sublane too.
+            if bk % lane:
                 bad(table, itemsize,
-                    f"bk={bk} not a multiple of sublane {sub}")
+                    f"bk={bk} not a multiple of the {lane} lane (bk is the "
+                    "last dim of the x block)")
             used = mod._vmem_bytes(bm, bn, bk, itemsize)
             if used > budget:
                 bad(table, itemsize,
@@ -186,6 +192,11 @@ def validate_tuning_tables(module=tuning) -> list[TileFinding]:
         for itemsize, (ppb, hb) in entries.items():
             if ppb < 1 or hb < 1:
                 bad(name, itemsize, f"degenerate blocks ({ppb},{hb})")
+            if hb % mod.DECODE_HEAD_TILE:
+                bad(name, itemsize,
+                    f"head_block={hb} not a multiple of "
+                    f"{mod.DECODE_HEAD_TILE}: below the whole KV-head axis "
+                    "the TPU compiler refuses it, so the clamp replaces it")
             # the kernel binds the pool once per page of the block
             used = 2 * ppb * 16 * hb * 128 * itemsize  # page=16, hd=128
             if used > mod._DECODE_ATTN_VMEM_BYTES:
@@ -202,6 +213,8 @@ def validate_tuning_tables(module=tuning) -> list[TileFinding]:
     for i, (bm, bn, bk) in enumerate(mod.AUTOTUNE_CANDIDATES):
         if bn % lane:
             bad("AUTOTUNE_CANDIDATES", i, f"bn={bn} not lane-aligned")
+        if bk % lane:
+            bad("AUTOTUNE_CANDIDATES", i, f"bk={bk} not lane-aligned")
         for itemsize in _ITEMSIZES:
             if mod._vmem_bytes(bm, bn, bk, itemsize) > budget:
                 bad("AUTOTUNE_CANDIDATES", i,
@@ -216,6 +229,18 @@ def validate_tuning_tables(module=tuning) -> list[TileFinding]:
             bad("DECODE_ATTN_CANDIDATES", i,
                 f"{cand} still over the VMEM budget after clamping")
 
+    # -- decode head block as the kernel sees it ------------------------
+    for hkv in _SWEEP_HKV:
+        for dtype in _SWEEP_DTYPES:
+            _, hb = mod.decode_attn_blocks(
+                pages_per_slot=64, n_kv_heads=hkv, page_size=16,
+                head_dim=128, storage_dtype=dtype,
+            )
+            if hkv % hb or (hb != hkv and hb % mod.DECODE_HEAD_TILE):
+                bad("decode_attn_blocks", f"Hkv={hkv},{jnp.dtype(dtype).name}",
+                    f"head_block={hb} is neither the whole KV-head axis nor "
+                    f"a multiple of {mod.DECODE_HEAD_TILE} dividing it")
+
     # -- sweep the real selection functions -----------------------------
     for dtype in _SWEEP_DTYPES:
         itemsize = jnp.dtype(dtype).itemsize
@@ -229,6 +254,9 @@ def validate_tuning_tables(module=tuning) -> list[TileFinding]:
                         bad("heuristic_block_sizes", entry,
                             f"bn={bn} not lane-aligned")
                         continue
+                    if bk % lane and bk < k:
+                        bad("heuristic_block_sizes", entry,
+                            f"bk={bk} neither lane-aligned nor the whole K")
                     if m <= mod._VERIFY_M and bm != m:
                         bad("heuristic_block_sizes", entry,
                             f"exact-M band returned bm={bm} != M={m} "

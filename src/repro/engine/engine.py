@@ -21,7 +21,7 @@ differentiable — see ``repro.engine.autodiff``), and :meth:`Engine.closure`
 Ambient selection uses :func:`engine_scope`, a ``contextvars``-based scope
 (race-free under threads and asyncio, unlike the module global it replaces):
 
-    eng = Engine(policy="redmule_hfp8", backend="pallas")
+    eng = Engine(policy="tpu_hfp8", backend="pallas")
     with engine_scope(eng):
         ...  # current_engine() inside resolves to eng
 
@@ -70,6 +70,15 @@ class Engine:
         if isinstance(self.policy, str):
             object.__setattr__(self, "policy", get_policy(self.policy))
         _check_backend(self.backend)
+        if self.backend == "pallas" and self.policy.compute == jnp.float16:
+            # Mosaic on TPU v5e cannot legalize the fp16 casts of the
+            # kernel's datapath ('tpu.pack_subelements').
+            raise ValueError(
+                f"policy {self.policy.name!r} computes in fp16, which the "
+                "Pallas TPU kernel cannot compile on v5e; use backend='xla' "
+                "or 'pallas_interpret', or a bf16-compute policy "
+                "(tpu_hfp8, tpu_bf16)"
+            )
 
     # -- geometry ----------------------------------------------------------
     @property

@@ -1,4 +1,4 @@
-"""Fused flash-attention Pallas kernel (TPU target, interpret-validated).
+"""Fused flash-attention Pallas kernels (TPU target; paged decode runs on v5e).
 
 This is the deployment path for the §Perf A.4 projection (EXPERIMENTS.md):
 the XLA-lowered online-softmax scan materializes per-chunk score tensors in
@@ -241,6 +241,7 @@ def paged_flash_decode_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, hkv, g, hd), q.dtype),
         interpret=interpret,
+        name="paged_flash_decode",
     )(
         page_table.astype(jnp.int32),
         seq_lens.astype(jnp.int32),

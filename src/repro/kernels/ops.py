@@ -20,6 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core import semiring
 from repro.core.precision import FP32_REF, PrecisionPolicy
@@ -215,15 +216,57 @@ def _pallas_gemm_op(
     elif y is not None:
         y3 = y.reshape(y.shape[-2:])
 
-    x3, w3, y3, (mo, no) = _pad_operands(x3, w3, y3, gop, bm, bn, bk)
-    z = redmule_gemm_pallas(
-        x3, w3, y3,
-        gop=gop, policy=policy,
-        block_m=bm, block_n=bn, block_k=bk,
-        out_dtype=out_dtype, interpret=interpret,
-    )
-    z = z[..., :mo, :no]
-    return z.reshape(out_batch + (mo, no))
+    def run(x3, w3, y3=None):
+        x3, w3, y3, (mo, no) = _pad_operands(x3, w3, y3, gop, bm, bn, bk)
+        z = redmule_gemm_pallas(
+            x3, w3, y3,
+            gop=gop, policy=policy,
+            block_m=bm, block_n=bn, block_k=bk,
+            out_dtype=out_dtype, interpret=interpret,
+        )
+        return z[..., :mo, :no]
+
+    operands = (x3, w3) if y3 is None else (x3, w3, y3)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.size > 1 and not mesh.are_all_axes_manual:
+        run = _shard_over_mesh(run, mesh, operands)
+    z = run(*operands)
+    return z.reshape(out_batch + (m, n))
+
+
+def _shard_over_mesh(run, mesh, operands):
+    """Run one kernel call per device of the ambient mesh.
+
+    Mosaic kernels cannot be partitioned by the compiler, so a GEMM traced
+    under a mesh (``jax.set_mesh`` or ``jax.sharding.use_abstract_mesh``)
+    is wrapped in ``shard_map``: rows (the batch axis, else M) split over
+    the data axes and N over ``model`` wherever they divide evenly, and K
+    is never split, so every output element is one whole kernel
+    accumulation, exactly as on one device.
+    """
+    x, w = operands[:2]
+    dp = tuple(a for a in mesh.axis_names if a != "model")
+    n_dp = 1
+    for a in dp:
+        n_dp *= mesh.shape[a]
+    n_ax = None
+    if "model" in mesh.axis_names and w.shape[-1] % mesh.shape["model"] == 0:
+        n_ax = "model"
+    b_ax = m_ax = None
+    if dp and x.ndim == 3 and x.shape[0] % n_dp == 0:
+        b_ax = dp
+    elif dp and x.shape[-2] % n_dp == 0:
+        m_ax = dp
+    batched = (b_ax,) if x.ndim == 3 else ()
+    x_spec = P(*batched, m_ax, None)
+    w_spec = P(b_ax, None, n_ax) if w.ndim == 3 else P(None, n_ax)
+    out_spec = P(*batched, m_ax, n_ax)
+    specs = [x_spec, w_spec]
+    if len(operands) == 3:
+        y = operands[2]
+        specs.append(P(b_ax, m_ax, n_ax) if y.ndim == 3 else P(m_ax, n_ax))
+    return jax.shard_map(run, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=out_spec, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

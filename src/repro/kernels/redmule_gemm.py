@@ -1,4 +1,4 @@
-"""RedMulE GEMM-Op Pallas kernel (TPU target, interpret-mode validated).
+"""RedMulE GEMM-Op Pallas kernel (TPU target; compiled and run on the v5e).
 
 TPU mapping of the paper's datapath (docs/DESIGN.md Sec. 2):
 
@@ -188,5 +188,6 @@ def redmule_gemm_pallas(
         out_shape=jax.ShapeDtypeStruct((b, m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), policy.acc)],
         interpret=interpret,
+        name="redmule_gemm",
     )(*operands)
     return out[0] if squeeze else out

@@ -14,8 +14,11 @@ Replaces the hardcoded 128^3 tiles with a three-level policy:
      "FP8 doubles effective bandwidth").
 
 All levels clamp tiles to the (padded) problem so small/ragged shapes never
-allocate oversized VMEM tiles; the lane (N) dimension stays a multiple of
-128 per the TPU tiling constraint.
+allocate oversized VMEM tiles. The TPU tiling constraint (Mosaic refuses a
+block whose last dim is not a multiple of the 128 lane, unless it spans
+the whole array dim) binds N, which is the last dim of the w and output
+blocks, and K, which is the last dim of the x block: every table K tile is
+a multiple of 128 as well as of the sublane.
 """
 from __future__ import annotations
 
@@ -77,13 +80,14 @@ _CHUNK_HEURISTIC = {
 # problems — big enough that a full 128-row M tile stops being padding
 # waste, small enough that the training table's balanced tiles leave VMEM
 # idle — so the M tile caps at 128 and the K tile sits between the chunk
-# and training depths.
+# and training depths (for 2- and 4-byte storage those are 256 and 128, and
+# no lane-aligned depth lies strictly between them).
 _BATCH_PREFILL_M = 512
 # (bk, bn) per storage byte-width for the batched-prefill table.
 _BATCH_PREFILL_HEURISTIC = {
     1: (384, 128),
-    2: (192, 128),
-    4: (192, 128),
+    2: (256, 128),
+    4: (256, 128),
 }
 # VMEM budget for one grid step's working set (x, w, y/out, acc tiles).
 _VMEM_BUDGET_BYTES = 8 * 1024 * 1024
@@ -93,22 +97,24 @@ _VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 # walks (more pages per step = fewer grid steps but a bigger VMEM working
 # set); head_block tiles the KV-head axis. fp8 pages are 1 B/elem, so twice
 # the pages fit the same VMEM budget — the same rule as the GEMM K tile.
+# The head block is the second-to-last dim of the (page_size, head_block,
+# head_dim) pool block, so Mosaic takes only the whole KV-head axis or a
+# multiple of 8 (see clamp_decode_attn_blocks).
+DECODE_HEAD_TILE = 8
 _DECODE_ATTN_HEURISTIC = {
-    1: (8, 1),
-    2: (4, 1),
-    4: (4, 1),
+    1: (8, 8),
+    2: (4, 8),
+    4: (4, 8),
 }
 # Candidate (pages_per_block, head_block) pairs swept by the decode-attn
 # autotuner (clamped/deduped per problem like the GEMM candidates).
 DECODE_ATTN_CANDIDATES = (
-    (1, 1),
-    (2, 1),
-    (4, 1),
-    (8, 1),
-    (16, 1),
-    (2, 2),
-    (4, 2),
-    (4, 4),
+    (1, 8),
+    (2, 8),
+    (4, 8),
+    (8, 8),
+    (16, 8),
+    (4, 16),
 )
 # VMEM budget for one decode-attn grid step (k+v pages, q, acc tiles).
 _DECODE_ATTN_VMEM_BYTES = 4 * 1024 * 1024
@@ -122,7 +128,6 @@ AUTOTUNE_CANDIDATES = (
     (64, 128, 128),
     (64, 128, 256),
     (32, 128, 512),
-    (128, 128, 64),
     # Skinny decode rows (M in {1, 2, 4, 8}); clamping dedupes these for
     # training-size problems so the sweep cost stays bounded.
     (1, 128, 512),
@@ -143,9 +148,8 @@ AUTOTUNE_CANDIDATES = (
     (16, 128, 384),
     # Batched multi-slot prefill (M = P x chunk, 64 < M <= 512): 128-cap M
     # tiles at the batched table's K depths, plus the neighbours the
-    # heuristic rejects (sub-128 M splits, a deeper fp8 K).
-    (96, 128, 192),
-    (128, 128, 192),
+    # heuristic rejects (a sub-128 M split, a deeper fp8 K).
+    (96, 128, 256),
     (128, 128, 384),
     (256, 128, 128),
 )
@@ -166,6 +170,14 @@ def _vmem_bytes(bm: int, bn: int, bk: int, itemsize: int, acc_itemsize: int = 4)
     operands = (bm * bk + bk * bn) * itemsize
     acc_and_out = 2 * bm * bn * acc_itemsize
     return operands + acc_and_out
+
+
+def _fit_vmem(bm: int, bn: int, bk: int, itemsize: int) -> int:
+    """Shrink the K tile until the working set fits the VMEM budget,
+    keeping it a multiple of the 128 lane."""
+    while _vmem_bytes(bm, bn, bk, itemsize) > _VMEM_BUDGET_BYTES and bk > LANE:
+        bk = max(LANE, bk // 2 // LANE * LANE)
+    return bk
 
 
 def clamp_blocks(
@@ -191,7 +203,8 @@ def heuristic_block_sizes(
     """Table-driven tile choice keyed on storage byte width, problem-clamped.
 
     Auto-selected tiles respect the dtype's TPU min-tile granularity: the
-    M/K tiles are multiples of SUBLANE[itemsize], N of the 128 lane —
+    M tile is a multiple of SUBLANE[itemsize], the N and K tiles multiples
+    of the 128 lane unless clamped to span the whole (padded) dim —
     except skinny decode rows (m <= _SKINNY_M), where block_m clamps to m
     exactly so one-token decode GEMMs don't pad to training tiles.
     """
@@ -204,8 +217,7 @@ def heuristic_block_sizes(
         # via the autotune cache). K tile deepens into the freed VMEM.
         bk, bn = _SKINNY_HEURISTIC.get(itemsize, (512, 128))
         bm = m
-        while _vmem_bytes(bm, bn, bk, itemsize) > _VMEM_BUDGET_BYTES and bk > sub:
-            bk //= 2
+        bk = _fit_vmem(bm, bn, bk, itemsize)
         _, bn, bk = clamp_blocks(bm, bn, bk, m, n, k, itemsize)
         return bm, _ceil_to(bn, LANE), _ceil_to(bk, sub)
     if m <= _VERIFY_M:
@@ -215,8 +227,7 @@ def heuristic_block_sizes(
         # tile between the skinny and chunk depths.
         bk, bn = _VERIFY_HEURISTIC.get(itemsize, (384, 128))
         bm = m
-        while _vmem_bytes(bm, bn, bk, itemsize) > _VMEM_BUDGET_BYTES and bk > sub:
-            bk //= 2
+        bk = _fit_vmem(bm, bn, bk, itemsize)
         _, bn, bk = clamp_blocks(bm, bn, bk, m, n, k, itemsize)
         return bm, _ceil_to(bn, LANE), _ceil_to(bk, sub)
     if m <= _CHUNK_M:
@@ -224,8 +235,7 @@ def heuristic_block_sizes(
         # grid, K tile deepened into the VMEM a 128-row tile would waste.
         bk, bn = _CHUNK_HEURISTIC.get(itemsize, (256, 128))
         bm = _ceil_to(m, sub)
-        while _vmem_bytes(bm, bn, bk, itemsize) > _VMEM_BUDGET_BYTES and bk > sub:
-            bk //= 2
+        bk = _fit_vmem(bm, bn, bk, itemsize)
         bm, bn, bk = clamp_blocks(bm, bn, bk, m, n, k, itemsize)
         return bm, _ceil_to(bn, LANE), _ceil_to(bk, sub)
     if m <= _BATCH_PREFILL_M:
@@ -234,15 +244,13 @@ def heuristic_block_sizes(
         # 96 rows rather than padding to 128x2 or falling into the
         # training table's shallower K. The K tile sits between the chunk
         # and training depths (bk_training <= bk_batched <= bk_chunk).
-        bk, bn = _BATCH_PREFILL_HEURISTIC.get(itemsize, (192, 128))
+        bk, bn = _BATCH_PREFILL_HEURISTIC.get(itemsize, (256, 128))
         bm = min(_ceil_to(m, sub), 128)
-        while _vmem_bytes(bm, bn, bk, itemsize) > _VMEM_BUDGET_BYTES and bk > sub:
-            bk //= 2
+        bk = _fit_vmem(bm, bn, bk, itemsize)
         bm, bn, bk = clamp_blocks(bm, bn, bk, m, n, k, itemsize)
         return bm, _ceil_to(bn, LANE), _ceil_to(bk, sub)
     bm, bn, bk = _HEURISTIC.get(itemsize, (128, 128, 128))
-    while _vmem_bytes(bm, bn, bk, itemsize) > _VMEM_BUDGET_BYTES and bk > sub:
-        bk //= 2
+    bk = _fit_vmem(bm, bn, bk, itemsize)
     bm, bn, bk = clamp_blocks(bm, bn, bk, m, n, k, itemsize)
     # Round auto tiles up to the sublane/lane grid (still <= the caps above,
     # which are sublane/lane multiples themselves).
@@ -392,7 +400,7 @@ def _env_decode_attn() -> tuple[int | None, int | None]:
     except ValueError:
         warnings.warn(
             f"ignoring malformed REPRO_DECODE_ATTN_BLOCKS={raw!r} "
-            "(expected 'pages_per_block,head_block', e.g. '4,1'); "
+            "(expected 'pages_per_block,head_block', e.g. '4,8'); "
             "using the heuristic table",
             stacklevel=3,
         )
@@ -405,12 +413,17 @@ def clamp_decode_attn_blocks(
     page_size: int, head_dim: int, itemsize: int,
 ) -> tuple[int, int]:
     """Clamp a (pages_per_block, head_block) pair to the problem: head_block
-    must divide the KV-head count, pages_per_block never exceeds the page
-    table width, and the k+v working set stays inside the VMEM budget."""
+    divides the KV-head count and is either the whole count or a multiple of
+    DECODE_HEAD_TILE (Mosaic's rule for the pool block's second-to-last
+    dim), pages_per_block never exceeds the page table width, and the k+v
+    working set stays inside the VMEM budget."""
     ppb = max(1, min(ppb, pages_per_slot))
     hb = max(1, min(hb, n_kv_heads))
-    while n_kv_heads % hb:
-        hb -= 1
+    if hb < n_kv_heads:
+        hb -= hb % DECODE_HEAD_TILE
+        while hb and n_kv_heads % hb:
+            hb -= DECODE_HEAD_TILE
+        hb = hb or n_kv_heads
     while (
         2 * ppb * page_size * hb * head_dim * itemsize > _DECODE_ATTN_VMEM_BYTES
         and ppb > 1
@@ -434,7 +447,7 @@ def decode_attn_blocks(
     same three-level policy)."""
     itemsize = jnp.dtype(storage_dtype).itemsize
     env = _env_decode_attn()
-    heur = _DECODE_ATTN_HEURISTIC.get(itemsize, (4, 1))
+    heur = _DECODE_ATTN_HEURISTIC.get(itemsize, (4, DECODE_HEAD_TILE))
     ppb, hb = (
         req if req is not None else (ev if ev is not None else hv)
         for req, ev, hv in zip(requested, env, heur)

@@ -31,7 +31,6 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import ARCH_IDS, SHAPES, cell_is_runnable, get_config  # noqa: E402
-from repro.distrib import compat  # noqa: E402
 from repro.distrib import sharding as shd  # noqa: E402
 from repro.launch.mesh import (  # noqa: E402
     dp_axes_of,
@@ -132,7 +131,7 @@ def lower_cell(arch: str, shape: str, mesh, *, args=None):
         bspecs = shd.batch_specs(batch_shape, dp_axes)
         bshard = shd.tree_shardings(bspecs, mesh)
         step = make_train_step(model, opt)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step,
                 in_shardings=(state_shard, bshard),
@@ -149,7 +148,7 @@ def lower_cell(arch: str, shape: str, mesh, *, args=None):
         bshard = shd.tree_shardings(bspecs, mesh)
         max_len = seq if not cfg.is_encoder_decoder else max(seq // cfg.enc_dec_ratio, 1)
         fn = lambda p, b: prefill_step(p, b, max_len)  # noqa: E731
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 fn, in_shardings=(pshard, bshard), out_shardings=None
             ).lower(params_shape, batch_shape)
@@ -160,7 +159,7 @@ def lower_cell(arch: str, shape: str, mesh, *, args=None):
     cspecs = shd.cache_specs(specs["cache"], cfg, dp_axes, tp, batch, n_dp)
     cshard = shd.tree_shardings(cspecs, mesh)
     tshard = NamedSharding(mesh, P(dp_axes if batch % n_dp == 0 else None, None))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             decode_step,
             in_shardings=(pshard, tshard, cshard),

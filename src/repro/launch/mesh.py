@@ -5,14 +5,13 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh(shape, axes):
-    """jax.make_mesh with explicit-Auto axis types where the installed jax
-    supports them (jax.sharding.AxisType landed after 0.4.x; older releases
-    treat every axis as Auto implicitly, which is the semantics we want)."""
-    kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kwargs)
+def make_mesh(shape, axes, devices=None):
+    """jax.make_mesh with every axis Auto (GSPMD propagates shardings),
+    over ``devices`` (default: all of them)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

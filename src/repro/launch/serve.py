@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 from repro.obs import JsonTracer, device_capture, write_metrics, write_trace
 from repro.serving import (
@@ -119,11 +120,14 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.fp8_kv:
         cfg = dataclasses.replace(cfg, kv_cache_dtype="e4m3")
     model = build(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    # Jitted init draws each weight on the device in its storage dtype
+    # (eagerly, every fp32 draw would be materialised before its cast).
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
     eng = model.engine.with_backend(args.backend) if args.backend else model.engine
     print(f"engine: policy={eng.policy.name} backend={eng.backend} "
           f"kv_dtype={cfg.kv_cache_dtype}")
@@ -148,7 +152,7 @@ def main(argv=None):
             if args.draft_model is not None:
                 dcfg = get_config(args.draft_model, smoke=args.smoke)
                 draft_model = build(dcfg)
-                draft_params = draft_model.init(
+                draft_params = jax.jit(draft_model.init)(
                     jax.random.PRNGKey(args.seed + 1)
                 )
 

@@ -24,6 +24,7 @@ from repro.configs import ARCH_IDS, get_config
 from repro.data.pipeline import for_model
 from repro.distrib import sharding as shd
 from repro.distrib.fault import Heartbeat, StragglerMonitor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import dp_axes_of, make_mesh, n_dp_of, tp_size_of
 from repro.models import build
 from repro.models.transformer import MeshCtx
@@ -39,6 +40,47 @@ def make_mesh_from_args(args):
         dims = (n_dev, 1)
     axes = ("pod", "data", "model")[3 - len(dims):]
     return make_mesh(dims, axes)
+
+
+def make_sharded_train(model, opt, mesh, batch_shape):
+    """Jitted ``(init_fn, step_fn, state_shard)`` for one training run.
+
+    Parameters follow ``distrib.sharding``'s tensor-parallel specs over
+    ``model``, AdamW moments add ZeRO-1 sharding over the data axes, and the
+    batch shards over the data axes. ``init_fn(key)`` builds the state on
+    the devices; ``step_fn(state, batch)`` donates the state and traces
+    under the mesh, so each Pallas GEMM runs once per device (see
+    ``kernels.ops``).
+    """
+    cfg = model.cfg
+    dp_axes, tp, n_dp = dp_axes_of(mesh), tp_size_of(mesh), n_dp_of(mesh)
+    params_shape = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pspecs = shd.param_specs(params_shape, cfg, tp)
+    pshard = shd.tree_shardings(pspecs, mesh)
+    mom_specs = shd.zero1_specs(pspecs, params_shape, dp_axes, n_dp)
+    oshard = shd.tree_shardings({"mu": mom_specs, "nu": mom_specs}, mesh)
+    scalar = NamedSharding(mesh, P())
+    state_shard = TrainState(scalar, pshard, oshard, scalar)
+    bshard = shd.tree_shardings(shd.batch_specs(batch_shape, dp_axes), mesh)
+    train_step = make_train_step(model, opt)
+
+    def init_state(key):
+        params = model.init(key)
+        zero = jnp.zeros((), jnp.int32)
+        return TrainState(zero, params, opt.init(params), zero)
+
+    def step(state, batch):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return train_step(state, batch)
+
+    init_fn = jax.jit(init_state, out_shardings=state_shard)
+    step_fn = jax.jit(
+        step,
+        in_shardings=(state_shard, bshard),
+        out_shardings=(state_shard, None),
+        donate_argnums=(0,),
+    )
+    return init_fn, step_fn, state_shard
 
 
 def main(argv=None):
@@ -78,31 +120,18 @@ def main(argv=None):
     if over:
         cfg = dataclasses.replace(cfg, **over)
 
+    enable_compile_cache()
     mesh = make_mesh_from_args(args)
-    dp_axes, tp, n_dp = dp_axes_of(mesh), tp_size_of(mesh), n_dp_of(mesh)
-    mesh_ctx = MeshCtx(mesh=mesh, dp_axes=dp_axes, ep_axis="model")
+    mesh_ctx = MeshCtx(mesh=mesh, dp_axes=dp_axes_of(mesh), ep_axis="model")
     model = build(cfg, mesh_ctx)
     # cfg.policy/cfg.backend (incl. the CLI overrides above) became the
     # model's Engine; every GEMM in the traced step runs on it.
     print(f"engine: policy={model.engine.policy.name} backend={model.engine.backend}")
 
     opt = AdamW(lr=cosine_schedule(args.lr, args.warmup, args.steps))
-    params_shape = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(args.seed)))
-    pspecs = shd.param_specs(params_shape, cfg, tp)
-    pshard = shd.tree_shardings(pspecs, mesh)
-    mom_specs = shd.zero1_specs(pspecs, params_shape, dp_axes, n_dp)
-    oshard = shd.tree_shardings({"mu": mom_specs, "nu": mom_specs}, mesh)
-    scalar = NamedSharding(mesh, P())
-    state_shard = TrainState(scalar, pshard, oshard, scalar)
-
-    init_fn = jax.jit(
-        lambda key: TrainState(
-            jnp.zeros((), jnp.int32),
-            model.init(key),
-            opt.init(model.init(key)),
-            jnp.zeros((), jnp.int32),
-        ),
-        out_shardings=state_shard,
+    data = for_model(cfg, args.seq, args.batch, seed=args.seed)
+    init_fn, step_fn, state_shard = make_sharded_train(
+        model, opt, mesh, jax.eval_shape(lambda: data.batch(0))
     )
 
     start_step = 0
@@ -118,17 +147,6 @@ def main(argv=None):
             print(f"resumed from step {start_step}")
     else:
         state = init_fn(jax.random.PRNGKey(args.seed))
-
-    data = for_model(cfg, args.seq, args.batch, seed=args.seed)
-    bshard = shd.tree_shardings(
-        shd.batch_specs(jax.eval_shape(lambda: data.batch(0)), dp_axes), mesh
-    )
-    step_fn = jax.jit(
-        make_train_step(model, opt),
-        in_shardings=(state_shard, bshard),
-        out_shardings=(state_shard, None),
-        donate_argnums=(0,),
-    )
 
     saver = ckpt.AsyncSaver()
     hb = Heartbeat(os.path.join(args.ckpt_dir or "/tmp/repro_hb", "hb"), 0)

@@ -67,10 +67,9 @@ class PagedInfo(NamedTuple):
         set AND fresh k/v appended) — distinguishes it from decode, which
         also sets read_idx but attends over the gathered keys only.
     pages: (B, pages_per_slot) physical page-table rows (NULL = 0), or None.
-        When set on a decode step and the engine backend is pallas, the
-        layer dispatches to the fused paged flash-decode kernel instead of
-        gathering through read_idx (the XLA gather path stays as the
-        reference oracle and CPU fallback).
+        Required on a decode step under a Pallas backend, which runs the
+        fused paged flash-decode kernel; the XLA backend gathers through
+        read_idx instead (the reference oracle the kernel is tested against).
     page_size: tokens per physical page (trace-time constant; only
         meaningful with ``pages``).
     """
@@ -292,12 +291,15 @@ def apply(
                 [cv[paged.read_idx].astype(engine.policy.compute), v], axis=1
             )
         elif paged.read_idx is not None:
-            if (
-                paged.pages is not None
-                and s == 1
-                and paged.active is not None
-                and engine.backend in ("pallas", "pallas_interpret")
-            ):
+            if engine.backend in ("pallas", "pallas_interpret"):
+                if paged.pages is None or paged.active is None or s != 1:
+                    raise ValueError(
+                        "paged decode under a Pallas backend runs the "
+                        "page-walk kernel and needs PagedInfo.pages and "
+                        f".active with one query token (got pages="
+                        f"{paged.pages is not None}, active="
+                        f"{paged.active is not None}, tokens={s})"
+                    )
                 # Decode via the fused paged flash-decode kernel: the page
                 # table is scalar-prefetched into the kernel, which walks
                 # exactly the pages each slot owns (fp8 pools dequantize
@@ -312,8 +314,8 @@ def apply(
                     backend=engine.backend,
                 )[:, None]  # (B, 1, Hq, hd)
             else:
-                # Decode: gather every slot's pages in position order
-                # (reference oracle / XLA-backend fallback).
+                # XLA decode: gather every slot's pages in position order
+                # (the reference oracle of the kernel above).
                 k = ck[paged.read_idx].astype(engine.policy.compute)
                 v = cv[paged.read_idx].astype(engine.policy.compute)
         k_pos = paged.k_pos

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distrib import compat
-from repro.distrib.compat import shard_map
 from repro.engine import Engine, as_engine
 from repro.models import common
 
@@ -104,7 +102,7 @@ def _ep_local(params, x, cfg: MoEConfig, engine: Engine, ep_axis: str):
     t = b * s
     x2 = x.reshape(t, d)
     e_local = params["up"].shape[0]
-    n_shards = compat.axis_size(ep_axis)
+    n_shards = jax.lax.axis_size(ep_axis)
     shard = jax.lax.axis_index(ep_axis)
     e_total = e_local * n_shards
 
@@ -157,7 +155,7 @@ def apply_ep(params, x, cfg: MoEConfig, engine: Engine, mesh, dp_axes, ep_axis):
         "gate": P(ep_axis),
         "down": P(ep_axis),
     }
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(pspec, P(dp_axes, None, None)),

@@ -101,20 +101,15 @@ class StepProfiler:
 @contextlib.contextmanager
 def device_capture(logdir: Optional[str]):
     """Opt-in ``jax.profiler`` capture window. ``logdir=None`` is a no-op
-    passthrough, so call sites can wrap unconditionally; a profiler that
-    fails to start (e.g. an already-active trace) degrades to a warning
-    rather than killing the serving run."""
+    passthrough, so call sites can wrap unconditionally. A profiler that
+    fails to start (e.g. an already-active trace) raises: a run asked to
+    trace must not finish untraced."""
     if not logdir:
         yield
         return
     import jax
 
-    try:
-        jax.profiler.start_trace(logdir)
-    except Exception as e:  # pragma: no cover - depends on runtime state
-        print(f"warning: jax.profiler capture unavailable ({e})")
-        yield
-        return
+    jax.profiler.start_trace(logdir)
     try:
         yield
     finally:

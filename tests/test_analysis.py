@@ -487,6 +487,45 @@ class TestTiles:
         )
         assert any("fp8" in f.detail for f in fs)
 
+    def test_lane_misaligned_k_tile_is_a_finding(self):
+        # The 192-deep batched-prefill K tile the TPU compiler refused:
+        # bk is the x block's last dim, so the sublane check alone passed it.
+        bad = {1: (384, 128), 2: (192, 128), 4: (192, 128)}
+        fs = tiles.validate_tuning_tables(
+            _fake_tuning(_BATCH_PREFILL_HEURISTIC=bad)
+        )
+        assert {f.entry for f in fs
+                if f.table == "_BATCH_PREFILL_HEURISTIC"
+                and "lane" in f.detail} == {"2", "4"}
+
+    def test_sub_tile_decode_head_block_is_a_finding(self):
+        # head_block=1 on an 8-KV-head pool: the TPU compiler refused it.
+        bad = {1: (8, 1), 2: (4, 1), 4: (4, 1)}
+        fs = tiles.validate_tuning_tables(
+            _fake_tuning(_DECODE_ATTN_HEURISTIC=bad)
+        )
+        assert {f.entry for f in fs
+                if f.table == "_DECODE_ATTN_HEURISTIC"
+                and "head_block" in f.detail} == {"1", "2", "4"}
+
+    def test_illegal_decode_head_block_clamp_is_a_finding(self):
+        # A clamp that only enforces divisibility hands 6 of 12 KV heads
+        # to the kernel: neither whole nor a multiple of 8.
+        def divisor_clamp(ppb, hb, *, pages_per_slot, n_kv_heads, **_):
+            hb = max(1, min(hb, n_kv_heads))
+            while n_kv_heads % hb:
+                hb -= 1
+            return max(1, min(ppb, pages_per_slot)), hb
+
+        mod = _fake_tuning(clamp_decode_attn_blocks=divisor_clamp)
+        mod.decode_attn_blocks = lambda **kw: divisor_clamp(
+            8, 8, pages_per_slot=kw["pages_per_slot"],
+            n_kv_heads=kw["n_kv_heads"],
+        )
+        fs = tiles.validate_tuning_tables(mod)
+        assert any(f.table == "decode_attn_blocks"
+                   and f.entry.startswith("Hkv=12,") for f in fs)
+
 
 # ---------------------------------------------------------------------------
 # CLI surface

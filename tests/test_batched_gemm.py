@@ -339,10 +339,10 @@ def test_batched_prefill_blocks_between_chunk_and_training():
     # band, 512 is its ceiling (P=8 x chunk 64), 513 falls to training.
     assert tuning.heuristic_block_sizes(64, 4096, 4096, jnp.float32)[0] == 64
     assert tuning.heuristic_block_sizes(65, 4096, 4096, jnp.float32)[0] == 72
-    assert tuning.heuristic_block_sizes(512, 4096, 4096, jnp.float32)[2] == 192
+    assert tuning.heuristic_block_sizes(512, 4096, 4096, jnp.float32)[2] == 256
     assert tuning.heuristic_block_sizes(513, 4096, 4096, jnp.float32)[2] == 128
     # The candidate list sweeps the batched band.
-    assert {(96, 128, 192), (128, 128, 192), (128, 128, 384),
+    assert {(96, 128, 256), (128, 128, 256), (128, 128, 384),
             (256, 128, 128)} <= set(tuning.AUTOTUNE_CANDIDATES)
 
 

@@ -433,6 +433,19 @@ def test_engine_validation_and_coercion():
     assert Engine().tile_cols == 16  # H*(P+1) default geometry
 
 
+@pytest.mark.parametrize("policy",
+                         ["redmule_fp16", "redmule_hfp8", "redmule_hfp8_out8"])
+def test_fp16_compute_refused_on_the_tpu_kernel(policy):
+    """Mosaic cannot lower the fp16 datapath: the Engine says so when it is
+    built, naming the policy, instead of failing inside a compile."""
+    with pytest.raises(ValueError, match=policy):
+        Engine(policy=policy, backend="pallas")
+    with pytest.raises(ValueError, match=policy):
+        Engine(policy="tpu_hfp8", backend="pallas").with_policy(policy)
+    for backend in ("xla", "pallas_interpret"):
+        assert Engine(policy=policy, backend=backend).backend == backend
+
+
 # ---------------------------------------------------------------------------
 # Deprecated shims
 # ---------------------------------------------------------------------------

@@ -468,3 +468,22 @@ def test_queue_wait_and_itl_histograms_populated(served_model):
         sum(_GENS) - len(_GENS)
     assert h["serving_prefill_chunk_seconds"]["count"] > 0
     assert h["serving_decode_step_seconds"]["count"] > 0
+
+
+def test_device_capture_raises_when_the_profiler_cannot_start(monkeypatch,
+                                                              tmp_path):
+    """A run asked for a device trace must not finish without one."""
+    from repro.obs.profiler import device_capture
+
+    with device_capture(None):
+        pass  # no logdir: a passthrough that never touches the profiler
+
+    def refuse(logdir):
+        raise RuntimeError("profiler already active")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    ran = []
+    with pytest.raises(RuntimeError, match="already active"):
+        with device_capture(str(tmp_path)):
+            ran.append(True)
+    assert not ran

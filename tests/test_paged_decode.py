@@ -227,8 +227,9 @@ def test_null_page_contributes_zero_weight(rng):
 
 def test_block_choice_invariance(rng):
     """(pages_per_block, head_block) is a scheduling choice, not semantics:
-    every tiling agrees up to online-softmax reassociation error."""
-    arrs = _make_case(rng, s=3, hq=8, hkv=4, hd=16, page_size=4,
+    every tiling agrees up to online-softmax reassociation error. 16 KV
+    heads leave two legal head blocks: 8 and the whole axis."""
+    arrs = _make_case(rng, s=3, hq=32, hkv=16, hd=16, page_size=4,
                       pages_per_slot=8, n_pages=16, dtype=jnp.float32)
     q, k_pool, v_pool, pt, seq_lens, active = arrs
     kw = dict(page_size=4, backend="pallas_interpret")
@@ -236,7 +237,7 @@ def test_block_choice_invariance(rng):
         np.asarray(ops.paged_decode_attention(
             q, k_pool, v_pool, pt, seq_lens, active,
             pages_per_block=ppb, head_block=hb, **kw))
-        for ppb, hb in ((1, 1), (2, 1), (4, 2), (8, 4), (3, 3))
+        for ppb, hb in ((1, 16), (2, 8), (4, 8), (8, 16), (3, 8))
     ]
     for o in outs[1:]:
         np.testing.assert_allclose(o, outs[0], rtol=1e-5, atol=1e-5)
@@ -266,14 +267,14 @@ def test_decode_attn_env_override(monkeypatch):
         pages_per_slot=8, n_kv_heads=4, page_size=8, head_dim=16,
         storage_dtype=jnp.float32,
     )
-    assert (ppb, hb) == (2, 2)
+    assert (ppb, hb) == (2, 4)
     monkeypatch.setenv("REPRO_DECODE_ATTN_BLOCKS", "garbage")
     with pytest.warns(UserWarning, match="REPRO_DECODE_ATTN_BLOCKS"):
         ppb, hb = tuning.decode_attn_blocks(
             pages_per_slot=8, n_kv_heads=4, page_size=8, head_dim=16,
             storage_dtype=jnp.float32,
         )
-    assert (ppb, hb) == (4, 1)  # falls back to the heuristic table
+    assert (ppb, hb) == (4, 4)  # falls back to the heuristic table
 
 
 # -- end-to-end -----------------------------------------------------------------
@@ -303,3 +304,27 @@ def test_server_greedy_parity_with_kernel_backend():
             max_new_tokens=6,
         )
         assert results[r.rid].out_tokens == list(ref[0]), f"prompt len {len(p)}"
+
+
+def test_pallas_decode_without_page_table_raises():
+    """Under a Pallas backend a decode step runs the page-walk kernel; a
+    caller that drops the page table gets an error, not the XLA gather."""
+    cfg = attention.AttnConfig(n_heads=4, n_kv_heads=2, head_dim=16)
+    params = attention.init(jax.random.PRNGKey(0), 32, cfg, jnp.float32)
+    pool = attention.init_paged_pool(5 * 4, cfg, jnp.float32)
+    x = jnp.ones((2, 1, 32), jnp.float32)
+    read_idx = jnp.arange(2 * 8, dtype=jnp.int32).reshape(2, 8)
+    paged = attention.PagedInfo(
+        write_idx=jnp.array([4, 12], jnp.int32), read_idx=read_idx,
+        k_pos=jnp.where(jnp.arange(8)[None] <= 1, jnp.arange(8)[None],
+                        attention.POS_SENTINEL),
+        slots=jnp.arange(2, dtype=jnp.int32), starts=jnp.ones(2, jnp.int32),
+        active=jnp.ones(2, bool),
+    )
+    positions = jnp.ones((2, 1), jnp.int32)
+    eng = Engine(policy="fp32", backend="pallas_interpret")
+    with pytest.raises(ValueError, match="pages"):
+        attention.apply(params, x, positions, cfg, eng, cache=pool, paged=paged)
+    out, _ = attention.apply(params, x, positions, cfg, eng.with_backend("xla"),
+                             cache=pool, paged=paged)
+    assert out.shape == (2, 1, 32)
