@@ -226,60 +226,68 @@ class Transformer:
     ):
         cfg = self.cfg
         new_cache = {} if cache is not None else None
-        h = common.norm_apply(p["norm1"], x, cfg.norm)
-        if kind in ("attn", "attn_local"):
-            acfg = self.attn_cfg(kind)
-            h, ac = attention.apply(
-                p["attn"], h, positions, acfg, engine,
-                cache=None if cache is None else cache["attn"],
-                causal=causal, mesh_ctx=self.mesh_ctx, paged=paged,
-            )
-            if new_cache is not None:
-                new_cache["attn"] = ac
-        elif kind in ("mlstm", "slstm", "rglru"):
-            h, st = self._recurrent_block(kind, p, h, cache, engine,
-                                          decode=decode, paged=paged)
-            if new_cache is not None:
-                new_cache["state"] = st
+        # Named scopes mark each layer's ops (casts and pads included) in
+        # the compiled program's metadata, so a profiler trace splits a
+        # step's device time by layer: embed, attn, recurrent, ffn, moe,
+        # lm_head, sample.
+        attn = kind in ("attn", "attn_local")
+        with jax.named_scope("attn" if attn else "recurrent"):
+            h = common.norm_apply(p["norm1"], x, cfg.norm)
+            if attn:
+                acfg = self.attn_cfg(kind)
+                h, ac = attention.apply(
+                    p["attn"], h, positions, acfg, engine,
+                    cache=None if cache is None else cache["attn"],
+                    causal=causal, mesh_ctx=self.mesh_ctx, paged=paged,
+                )
+                if new_cache is not None:
+                    new_cache["attn"] = ac
+            elif kind in ("mlstm", "slstm", "rglru"):
+                h, st = self._recurrent_block(kind, p, h, cache, engine,
+                                              decode=decode, paged=paged)
+                if new_cache is not None:
+                    new_cache["state"] = st
         x = x + h
         if "cross" in p:
-            hx = common.norm_apply(p["norm_x"], x, cfg.norm)
-            if enc_out is None:
-                # decode: use the cross-KV cached at prefill time
-                ck = cache["cross_k"].astype(engine.policy.compute)
-                cv = cache["cross_v"].astype(engine.policy.compute)
-                cp = enc_pos
-                new_cache["cross_k"] = cache["cross_k"]
-                new_cache["cross_v"] = cache["cross_v"]
-            else:
-                acfg = self.attn_cfg("attn")
-                ck = common.dense_apply(p["cross"]["k"], enc_out, engine)
-                cv = common.dense_apply(p["cross"]["v"], enc_out, engine)
-                b, se, _ = enc_out.shape
-                ck = ck.reshape(b, se, acfg.n_kv_heads, acfg.head_dim)
-                cv = cv.reshape(b, se, acfg.n_kv_heads, acfg.head_dim)
-                cp = enc_pos
-                if new_cache is not None:
-                    new_cache["cross_k"] = ck.astype(self.kv_dtype)
-                    new_cache["cross_v"] = cv.astype(self.kv_dtype)
-                ck = ck.astype(engine.policy.compute)
-                cv = cv.astype(engine.policy.compute)
-            hx, _ = attention.apply(
-                p["cross"], hx, positions, self.attn_cfg("attn"), engine,
-                cross_kv=(ck, cv, cp), mesh_ctx=self.mesh_ctx,
-            )
+            with jax.named_scope("attn"):
+                hx = common.norm_apply(p["norm_x"], x, cfg.norm)
+                if enc_out is None:
+                    # decode: use the cross-KV cached at prefill time
+                    ck = cache["cross_k"].astype(engine.policy.compute)
+                    cv = cache["cross_v"].astype(engine.policy.compute)
+                    cp = enc_pos
+                    new_cache["cross_k"] = cache["cross_k"]
+                    new_cache["cross_v"] = cache["cross_v"]
+                else:
+                    acfg = self.attn_cfg("attn")
+                    ck = common.dense_apply(p["cross"]["k"], enc_out, engine)
+                    cv = common.dense_apply(p["cross"]["v"], enc_out, engine)
+                    b, se, _ = enc_out.shape
+                    ck = ck.reshape(b, se, acfg.n_kv_heads, acfg.head_dim)
+                    cv = cv.reshape(b, se, acfg.n_kv_heads, acfg.head_dim)
+                    cp = enc_pos
+                    if new_cache is not None:
+                        new_cache["cross_k"] = ck.astype(self.kv_dtype)
+                        new_cache["cross_v"] = cv.astype(self.kv_dtype)
+                    ck = ck.astype(engine.policy.compute)
+                    cv = cv.astype(engine.policy.compute)
+                hx, _ = attention.apply(
+                    p["cross"], hx, positions, self.attn_cfg("attn"), engine,
+                    cross_kv=(ck, cv, cp), mesh_ctx=self.mesh_ctx,
+                )
             x = x + hx
         aux = jnp.zeros((), jnp.float32)
         if "ffn" in p or "moe" in p:
-            h2 = common.norm_apply(p["norm2"], x, cfg.norm)
-            if "moe" in p:
-                mc = self.mesh_ctx
-                h2, aux = moe.apply(
-                    p["moe"], h2, self.moe_cfg, engine,
-                    mesh=mc.mesh, dp_axes=mc.dp_axes, ep_axis=mc.ep_axis,
-                )
-            else:
-                h2 = ffn.apply(p["ffn"], h2, cfg.act, engine)
+            with jax.named_scope("moe" if "moe" in p else "ffn"):
+                h2 = common.norm_apply(p["norm2"], x, cfg.norm)
+                if "moe" in p:
+                    mc = self.mesh_ctx
+                    h2, aux = moe.apply(
+                        p["moe"], h2, self.moe_cfg, engine,
+                        mesh=mc.mesh, dp_axes=mc.dp_axes, ep_axis=mc.ep_axis,
+                    )
+                else:
+                    h2 = ffn.apply(p["ffn"], h2, cfg.act, engine)
             x = x + h2
         return x, new_cache, aux
 
@@ -397,8 +405,9 @@ class Transformer:
     # -- embedding / heads ----------------------------------------------------
     def embed(self, params, tokens, engine: Engine | None = None):
         eng = as_engine(engine) if engine is not None else self.engine
-        x = common.embed_apply(params["embed"], tokens).astype(eng.policy.compute)
-        return x * self.embed_scale
+        with jax.named_scope("embed"):
+            x = common.embed_apply(params["embed"], tokens).astype(eng.policy.compute)
+            return x * self.embed_scale
 
     def logits(self, params, h, engine: Engine | None = None):
         eng = as_engine(engine) if engine is not None else self.engine
@@ -651,9 +660,10 @@ class Transformer:
         x, new_pools, _ = self._run_stack(
             params["decoder"], x, positions, eng, cache=pools, paged=paged
         )
-        x = common.norm_apply(params["final_norm"], x, self.cfg.norm)
-        x_last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
-        logits = self.logits(params, x_last, engine=eng)
+        with jax.named_scope("lm_head"):
+            x = common.norm_apply(params["final_norm"], x, self.cfg.norm)
+            x_last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
+            logits = self.logits(params, x_last, engine=eng)
         return logits[:, 0], new_pools
 
     def _prefill_cb_batched(self, params, tokens, pools, page_rows, slots,
@@ -692,10 +702,11 @@ class Transformer:
         x, new_pools, _ = self._run_stack(
             params["decoder"], x, pos, eng, cache=pools, paged=paged
         )
-        x = common.norm_apply(params["final_norm"], x, self.cfg.norm)
-        last = jnp.clip(lengths - 1, 0, s - 1)[:, None, None]
-        x_last = jnp.take_along_axis(x, last, axis=1)  # (P, 1, D)
-        logits = self.logits(params, x_last, engine=eng)
+        with jax.named_scope("lm_head"):
+            x = common.norm_apply(params["final_norm"], x, self.cfg.norm)
+            last = jnp.clip(lengths - 1, 0, s - 1)[:, None, None]
+            x_last = jnp.take_along_axis(x, last, axis=1)  # (P, 1, D)
+            logits = self.logits(params, x_last, engine=eng)
         return logits[:, 0], new_pools
 
     def decode_cb(self, params, tokens, pools, page_table, seq_lens, active,
@@ -734,8 +745,9 @@ class Transformer:
             params["decoder"], x, positions, eng, cache=pools, decode=True,
             paged=paged,
         )
-        x = common.norm_apply(params["final_norm"], x, self.cfg.norm)
-        logits = self.logits(params, x, engine=eng)
+        with jax.named_scope("lm_head"):
+            x = common.norm_apply(params["final_norm"], x, self.cfg.norm)
+            logits = self.logits(params, x, engine=eng)
         return logits[:, 0], new_pools
 
     def verify_cb(self, params, tokens, pools, page_table, seq_lens, lengths,
@@ -793,8 +805,9 @@ class Transformer:
         x, new_pools, _ = self._run_stack(
             params["decoder"], x, pos, eng, cache=pools, paged=paged
         )
-        x = common.norm_apply(params["final_norm"], x, self.cfg.norm)
-        logits = self.logits(params, x, engine=eng)
+        with jax.named_scope("lm_head"):
+            x = common.norm_apply(params["final_norm"], x, self.cfg.norm)
+            logits = self.logits(params, x, engine=eng)
         return logits, new_pools
 
     def prefill(self, params, batch, cache, *, engine: Engine | None = None):
@@ -816,8 +829,9 @@ class Transformer:
             params["decoder"], x, positions, eng, cache=cache,
             enc_out=enc_out, enc_pos=enc_pos,
         )
-        x = common.norm_apply(params["final_norm"], x, cfg.norm)
-        logits = self.logits(params, x[:, -1:], engine=eng)
+        with jax.named_scope("lm_head"):
+            x = common.norm_apply(params["final_norm"], x, cfg.norm)
+            logits = self.logits(params, x[:, -1:], engine=eng)
         new_cache["pos"] = cache["pos"] + x.shape[1]
         new_cache["enc_pos"] = cache["enc_pos"]
         return logits, new_cache
@@ -831,8 +845,9 @@ class Transformer:
             params["decoder"], x, positions, eng, cache=cache, decode=True,
             enc_pos=cache.get("enc_pos"),
         )
-        x = common.norm_apply(params["final_norm"], x, self.cfg.norm)
-        logits = self.logits(params, x, engine=eng)
+        with jax.named_scope("lm_head"):
+            x = common.norm_apply(params["final_norm"], x, self.cfg.norm)
+            logits = self.logits(params, x, engine=eng)
         new_cache["pos"] = cache["pos"] + 1
         new_cache["enc_pos"] = cache["enc_pos"]
         return logits, new_cache

@@ -1,10 +1,18 @@
 """Request-lifecycle and device-step tracing.
 
-Two tracers share one protocol (``begin`` / ``end`` / ``instant`` /
-``reset``):
+Two tracers share one protocol (``span`` / ``begin`` / ``end`` /
+``instant`` / ``complete`` / ``reset``). ``span`` is the one context
+manager for a phase of the host's step loop, and it writes two sinks:
 
-:class:`NullTracer` — the default. Every method is a no-op and
-``enabled`` is False so instrumented hot paths can skip building the
+- the profiler's own trace, always: it enters a
+  ``jax.profiler.TraceAnnotation`` under the span's name and args, so
+  ``launch/serve.py --profile`` (or any ``jax.profiler`` session) shows the
+  phase on the same clock as the device's ops. Outside a profiler session
+  the annotation is a no-op and its args are never encoded;
+- this tracer's B/E pair, when ``enabled``.
+
+:class:`NullTracer` — the default. Every method but ``span`` is a no-op
+and ``enabled`` is False so instrumented hot paths can skip building the
 argument dicts entirely; an instrumented server with the NullTracer is
 behaviourally (bitwise, for greedy outputs) identical to the
 pre-instrumentation server because tracing never touches the RNG, the
@@ -31,12 +39,18 @@ Track layout (see docs/DESIGN.md, Observability):
   ``prefill_chunk`` (one per chunk), and ``decode`` (first token ->
   finish), plus ``admitted`` / ``preempted`` / ``finished`` instants
   carrying prefix-hit, preemption and speculative annotations.
-- ``pid == PID_DEVICE``, ``tid == DEVICE_TID`` ("steps"): host-side span
-  per jitted-step *dispatch* (``prefill_full.dispatch`` /
+- ``pid == PID_DEVICE``, ``tid == DEVICE_TID`` ("steps"): the phases of
+  the host's step loop, written through ``span`` and so also in a profiler
+  trace: ``server.step`` (one per ``Server.step()``) holding
+  ``server.admit`` (admission, preemption, prefix mapping, gauges), one
+  span per jitted-step *dispatch* (``prefill_full.dispatch`` /
   ``prefill_chunk.dispatch`` / ``prefill_batch.dispatch`` /
-  ``decode.dispatch``, plus the synchronous ``spec_round`` with nested
-  ``draft`` / ``verify`` / ``commit`` phases). Under the async engine the
-  dispatch span covers only the host time to enqueue the device work.
+  ``decode.dispatch``, or the synchronous ``spec_round`` with nested
+  ``draft`` / ``verify`` / ``commit`` phases), ``harvest.wait`` (the block
+  on the oldest step and the copy of its sampled tokens to the host) and
+  ``server.commit`` (token events, finishes and page recycling of that
+  step). Under the async engine the dispatch span covers only the host
+  time to enqueue the device work.
 - ``pid == PID_DEVICE``, ``tid == DEVICE_INFLIGHT_TID`` ("in flight"):
   one Chrome *complete* ("X") event per harvested step
   (``<kind>.complete``), backdated to its dispatch time and spanning
@@ -48,9 +62,12 @@ Track layout (see docs/DESIGN.md, Observability):
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import time
-from typing import Optional, Protocol, runtime_checkable
+from typing import Iterator, Optional, Protocol, runtime_checkable
+
+import jax
 
 PID_REQUESTS = 1
 PID_DEVICE = 2
@@ -66,6 +83,9 @@ class Tracer(Protocol):
 
     enabled: bool
 
+    def span(self, pid: int, tid: int, name: str,
+             **args) -> contextlib.AbstractContextManager: ...
+
     def begin(self, pid: int, tid: int, name: str, **args) -> None: ...
 
     def end(self, pid: int, tid: int, name: str, **args) -> None: ...
@@ -78,9 +98,30 @@ class Tracer(Protocol):
     def reset(self) -> None: ...
 
 
-class NullTracer:
-    """Zero-overhead default: all methods no-ops, ``enabled`` is False so
-    callers can skip even building kwargs for hot-path events."""
+class _Spans:
+    """The one ``span`` implementation both tracers share."""
+
+    enabled: bool
+
+    @contextlib.contextmanager
+    def span(self, pid: int, tid: int, name: str, **args) -> Iterator[None]:
+        """A phase of the step loop: a profiler annotation always, and a
+        B/E pair on track ``(pid, tid)`` when this tracer records."""
+        with jax.profiler.TraceAnnotation(name, **args):
+            if not self.enabled:
+                yield
+                return
+            self.begin(pid, tid, name, **args)
+            try:
+                yield
+            finally:
+                self.end(pid, tid, name)
+
+
+class NullTracer(_Spans):
+    """Zero-overhead default: B/E and instant methods are no-ops and
+    ``enabled`` is False, so callers can skip even building kwargs for
+    hot-path events; ``span`` still annotates the profiler's trace."""
 
     enabled = False
 
@@ -100,7 +141,7 @@ class NullTracer:
         pass
 
 
-class JsonTracer:
+class JsonTracer(_Spans):
     """In-memory trace recorder with Chrome trace-event / JSONL export."""
 
     enabled = True

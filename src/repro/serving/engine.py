@@ -138,10 +138,6 @@ class EngineCore:
         m = self.metrics
         self._g_inflight = m.gauge(
             "engine_inflight", "device steps dispatched but not yet harvested")
-        self._h_idle = m.histogram(
-            "engine_idle_seconds",
-            help="host blocking wait per harvest (0 when the step already "
-                 "finished — the overlap window covered it)")
         self._c_prefill_s = m.counter(
             "serving_prefill_seconds_total", "wall seconds in prefill steps")
         self._c_decode_s = m.counter(
@@ -246,28 +242,26 @@ class EngineCore:
         ``prefill_chunk``). ``sampling`` non-None marks the final chunk:
         the first token is sampled on-device and scattered into the
         last-token array so decode can be dispatched against it."""
-        t = self.tracer
         targs = {"rid": rid, "slot": slot, "start": start, "tokens": n,
                  "bucket": bucket}
-        if t.enabled:
-            t.begin(PID_DEVICE, DEVICE_TID, f"{kind}.dispatch", **targs)
         t0 = time.perf_counter()
-        fn = self._prefill_full if kind == "prefill_full" else self._prefill_chunk
-        logits, pools = fn(
-            self.params, jnp.asarray(tokens), self.cache.pools,
-            jnp.asarray(page_row), jnp.int32(slot), jnp.int32(start),
-            jnp.int32(n),
-        )
-        self.cache.pools = pools
-        toks = None
-        if sampling is not None:
-            toks = self._sample(logits, self.next_key(),
-                                **stack_params([sampling]))
-            self._last_tok = self._last_set(
-                self._last_tok, jnp.int32(slot), toks[0]
+        with self.tracer.span(PID_DEVICE, DEVICE_TID, f"{kind}.dispatch",
+                              **targs):
+            fn = (self._prefill_full if kind == "prefill_full"
+                  else self._prefill_chunk)
+            logits, pools = fn(
+                self.params, jnp.asarray(tokens), self.cache.pools,
+                jnp.asarray(page_row), jnp.int32(slot), jnp.int32(start),
+                jnp.int32(n),
             )
-        if t.enabled:
-            t.end(PID_DEVICE, DEVICE_TID, f"{kind}.dispatch")
+            self.cache.pools = pools
+            toks = None
+            if sampling is not None:
+                toks = self._sample(logits, self.next_key(),
+                                    **stack_params([sampling]))
+                self._last_tok = self._last_set(
+                    self._last_tok, jnp.int32(slot), toks[0]
+                )
         self._record(InflightStep(
             kind=kind, bucket=bucket, t_dispatch=t0, done=logits, toks=toks,
             payload=payload, trace_args=targs,
@@ -286,28 +280,26 @@ class EngineCore:
         into the last-token array."""
         p, chunk = tokens.shape
         bucket = p * chunk  # effective GEMM M — the tuning band's key
-        t = self.tracer
         # repro: allow[RPR106] active is a host numpy array — no device sync
         targs = {"rows": int(active.sum()), "P": p, "chunk": chunk,
                  "bucket": bucket}
         if rids is not None:
             targs["rids"] = list(rids)
-        if t.enabled:
-            t.begin(PID_DEVICE, DEVICE_TID, "prefill_batch.dispatch", **targs)
         t0 = time.perf_counter()
-        logits, pools = self._prefill_batch(
-            self.params, jnp.asarray(tokens), self.cache.pools,
-            jnp.asarray(page_rows), jnp.asarray(slots), jnp.asarray(starts),
-            jnp.asarray(lengths), jnp.asarray(active),
-        )
-        self.cache.pools = pools
-        toks = self._sample(logits, self.next_key(),
-                            **stack_params(sampling_list))
-        self._last_tok = self._last_set_rows(
-            self._last_tok, jnp.asarray(slots), toks, jnp.asarray(final_mask)
-        )
-        if t.enabled:
-            t.end(PID_DEVICE, DEVICE_TID, "prefill_batch.dispatch")
+        with self.tracer.span(PID_DEVICE, DEVICE_TID, "prefill_batch.dispatch",
+                              **targs):
+            logits, pools = self._prefill_batch(
+                self.params, jnp.asarray(tokens), self.cache.pools,
+                jnp.asarray(page_rows), jnp.asarray(slots),
+                jnp.asarray(starts), jnp.asarray(lengths), jnp.asarray(active),
+            )
+            self.cache.pools = pools
+            toks = self._sample(logits, self.next_key(),
+                                **stack_params(sampling_list))
+            self._last_tok = self._last_set_rows(
+                self._last_tok, jnp.asarray(slots), toks,
+                jnp.asarray(final_mask)
+            )
         self._record(InflightStep(
             kind="prefill_batch", bucket=bucket, t_dispatch=t0, done=logits,
             toks=toks, payload=payload, trace_args=targs,
@@ -319,27 +311,28 @@ class EngineCore:
         device last-token array (no host sync); the sampled tokens merge
         back into it for the next decode."""
         n = self.cache.num_slots
-        t = self.tracer
+        # ``ctx``: the decoding rows' contexts summed, the new token
+        # included — the KV positions the step's attention reads.
+        # repro: allow[RPR106] active and seq_lens are host numpy arrays
+        ctx = int((self.cache.seq_lens[active] + 1).sum())
         # repro: allow[RPR106] active is a host numpy array — no device sync
-        targs = {"slots": n, "decoding": int(active.sum())}
-        if t.enabled:
-            t.begin(PID_DEVICE, DEVICE_TID, "decode.dispatch", **targs)
+        targs = {"slots": n, "decoding": int(active.sum()), "ctx": ctx}
         t0 = time.perf_counter()
-        active_dev = jnp.asarray(active)
-        # .copy(): on CPU backends device_put of a numpy array may be
-        # zero-copy, aliasing the live host mirror — which the server
-        # mutates right after dispatch. The snapshot must be immutable.
-        logits, pools = self._decode(
-            self.params, self._last_tok, self.cache.pools,
-            jnp.asarray(self.cache.page_table.copy()),
-            jnp.asarray(self.cache.seq_lens.copy()), active_dev,
-        )
-        self.cache.pools = pools
-        toks = self._sample(logits, self.next_key(),
-                            **stack_params(params_list))
-        self._last_tok = self._last_merge(self._last_tok, toks, active_dev)
-        if t.enabled:
-            t.end(PID_DEVICE, DEVICE_TID, "decode.dispatch")
+        with self.tracer.span(PID_DEVICE, DEVICE_TID, "decode.dispatch",
+                              **targs):
+            active_dev = jnp.asarray(active)
+            # .copy(): on CPU backends device_put of a numpy array may be
+            # zero-copy, aliasing the live host mirror — which the server
+            # mutates right after dispatch. The snapshot must be immutable.
+            logits, pools = self._decode(
+                self.params, self._last_tok, self.cache.pools,
+                jnp.asarray(self.cache.page_table.copy()),
+                jnp.asarray(self.cache.seq_lens.copy()), active_dev,
+            )
+            self.cache.pools = pools
+            toks = self._sample(logits, self.next_key(),
+                                **stack_params(params_list))
+            self._last_tok = self._last_merge(self._last_tok, toks, active_dev)
         self._record(InflightStep(
             kind="decode", bucket=n, t_dispatch=t0, done=logits, toks=toks,
             payload=payload, trace_args=targs,
@@ -358,10 +351,11 @@ class EngineCore:
             return None
         rec = self._inflight.popleft()
         t_wait = time.perf_counter()
-        jax.block_until_ready(rec.done)
-        toks = np.asarray(rec.toks) if rec.toks is not None else None
+        with self.tracer.span(PID_DEVICE, DEVICE_TID, "harvest.wait",
+                              kind=rec.kind):
+            jax.block_until_ready(rec.done)
+            toks = np.asarray(rec.toks) if rec.toks is not None else None
         t_done = time.perf_counter()
-        self._h_idle.observe(t_done - t_wait)
         dt = t_done - max(rec.t_dispatch, self._t_last_harvest)
         self._t_last_harvest = t_done
         if rec.kind.startswith("prefill"):

@@ -74,8 +74,9 @@ def sample_logits(logits, key, temperature, top_k, top_p):
     Rows with temperature <= 0 take the argmax; the random draw still
     happens for every row (fixed shape) and is discarded there.
     """
-    logits = logits.astype(jnp.float32)
-    greedy = jnp.argmax(logits, axis=-1)
-    scaled = filter_logits(logits, temperature, top_k, top_p)
-    sampled = jax.random.categorical(key, scaled, axis=-1)
-    return jnp.where(temperature <= 0.0, greedy, sampled).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        logits = logits.astype(jnp.float32)
+        greedy = jnp.argmax(logits, axis=-1)
+        scaled = filter_logits(logits, temperature, top_k, top_p)
+        sampled = jax.random.categorical(key, scaled, axis=-1)
+        return jnp.where(temperature <= 0.0, greedy, sampled).astype(jnp.int32)

@@ -503,14 +503,19 @@ class Server:
         (batched when ``prefill_batch``) and one decode over all slots,
         then harvest in-flight steps down to ``async_depth``. Returns the
         tokens harvested (possibly empty while work is still in flight)."""
+        with self.tracer.span(PID_DEVICE, DEVICE_TID, "server.step"):
+            return self._step()
+
+    def _step(self) -> list[TokenEvent]:
         events: list[TokenEvent] = []
-        for req in self.scheduler.admit(on_preempt=self._on_preempt):
-            self._install(req)
-        # The scheduler's counters are the single authority; the registry
-        # gauges mirror them for reporting/exposition.
-        self._g_prefix_hit.set(self.scheduler.prefix_hit_tokens)
-        self._g_prefix_prompt.set(self.scheduler.prefix_prompt_tokens)
-        self._g_preemptions.set(self.scheduler.preemptions)
+        with self.tracer.span(PID_DEVICE, DEVICE_TID, "server.admit"):
+            for req in self.scheduler.admit(on_preempt=self._on_preempt):
+                self._install(req)
+            # The scheduler's counters are the single authority; the
+            # registry gauges mirror them for reporting/exposition.
+            self._g_prefix_hit.set(self.scheduler.prefix_hit_tokens)
+            self._g_prefix_prompt.set(self.scheduler.prefix_prompt_tokens)
+            self._g_preemptions.set(self.scheduler.preemptions)
         prefilling = [req for req in self.scheduler.running.values()
                       if self._dispatch_prefilling(req)]
         dispatched = 0
@@ -807,7 +812,11 @@ class Server:
         res = self.engine.harvest_one()
         if res is None:
             return False
-        rec, toks = res
+        with self.tracer.span(PID_DEVICE, DEVICE_TID, "server.commit"):
+            self._commit_step(*res, events)
+        return True
+
+    def _commit_step(self, rec, toks, events: list[TokenEvent]) -> None:
         if rec.kind == "decode":
             committed = 0
             for slot, req, epoch in rec.payload:
@@ -834,7 +843,6 @@ class Server:
                 self._c_prefill_tokens.inc(n)
                 if final:
                     self._commit(req, int(toks[i]), events)
-        return True
 
     def _spec_decode_once(self, events: list[TokenEvent]) -> None:
         """One speculative round over every decoding slot: draft k, verify
@@ -871,61 +879,55 @@ class Server:
             contexts[slot] = req.prompt + req.out_tokens
             params_list[slot] = req.sampling
         t = self.tracer
-        if t.enabled:
-            t.begin(PID_DEVICE, DEVICE_TID, "spec_round",
-                    slots=n, decoding=len(decoding), k=spec.k)
-            t.begin(PID_DEVICE, DEVICE_TID, "draft")
-        t0 = time.perf_counter()
-        proposal = self.drafter.propose(
-            contexts, want, self._next_key(), params_list,
-        )
-        if t.enabled:
-            t.end(PID_DEVICE, DEVICE_TID, "draft")
-        k_eff = np.minimum(want, proposal.counts)
-        lengths = np.where(active, k_eff + 1, 0).astype(np.int32)
-        tokens = np.zeros((n, width), np.int32)
-        for slot, req in decoding:
-            tokens[slot, 0] = req.out_tokens[-1]
-            m = int(k_eff[slot])
-            tokens[slot, 1:1 + m] = proposal.tokens[slot, :m]
-        if self.profile.needs_kv_pages:
+        with t.span(PID_DEVICE, DEVICE_TID, "spec_round",
+                    slots=n, decoding=len(decoding), k=spec.k):
+            t0 = time.perf_counter()
+            with t.span(PID_DEVICE, DEVICE_TID, "draft"):
+                proposal = self.drafter.propose(
+                    contexts, want, self._next_key(), params_list,
+                )
+            k_eff = np.minimum(want, proposal.counts)
+            lengths = np.where(active, k_eff + 1, 0).astype(np.int32)
+            tokens = np.zeros((n, width), np.int32)
             for slot, req in decoding:
-                grown = self.scheduler.ensure_pages(
-                    req, int(self.cache.seq_lens[slot]) + int(lengths[slot]))
-                self._mirror_pages(req, grown)
-        sp = stack_params(params_list)
-        # repro: allow[RPR105] spec round is host-synchronous; no mirror write before commit reads it
-        seq_lens_dev = jnp.asarray(self.cache.seq_lens)
-        # repro: allow[RPR105] spec round is host-synchronous; no mirror write before commit reads it
-        page_table_dev = jnp.asarray(self.cache.page_table)
-        active_dev = jnp.asarray(active)
-        if t.enabled:
-            t.begin(PID_DEVICE, DEVICE_TID, "verify",
-                    width=width, rows=len(decoding))
-        logits, pools = self.verifier.verify(
-            self.params, jnp.asarray(tokens), self.cache.pools,
-            page_table_dev, seq_lens_dev, jnp.asarray(lengths), active_dev,
-        )
-        out, acc = self.verifier.sample(
-            logits, jnp.asarray(tokens[:, 1:]), proposal.logits,
-            self._next_key(), sp, jnp.asarray(lengths), active_dev,
-        )
-        out = np.asarray(out)
-        acc = np.asarray(acc)
-        if t.enabled:
-            t.end(PID_DEVICE, DEVICE_TID, "verify")
-            t.begin(PID_DEVICE, DEVICE_TID, "commit")
-        if self.verifier.needs_state_commit:
-            commit_lengths = np.where(active, acc + 1, 0).astype(np.int32)
-            pools = self.verifier.commit_state(
-                self.params, jnp.asarray(tokens), pools, page_table_dev,
-                seq_lens_dev, jnp.asarray(commit_lengths), active_dev,
-            )
-        jax.block_until_ready(pools)
-        dt = time.perf_counter() - t0
-        if t.enabled:
-            t.end(PID_DEVICE, DEVICE_TID, "commit")
-            t.end(PID_DEVICE, DEVICE_TID, "spec_round")
+                tokens[slot, 0] = req.out_tokens[-1]
+                m = int(k_eff[slot])
+                tokens[slot, 1:1 + m] = proposal.tokens[slot, :m]
+            if self.profile.needs_kv_pages:
+                for slot, req in decoding:
+                    grown = self.scheduler.ensure_pages(
+                        req,
+                        int(self.cache.seq_lens[slot]) + int(lengths[slot]))
+                    self._mirror_pages(req, grown)
+            sp = stack_params(params_list)
+            # repro: allow[RPR105] spec round is host-synchronous; no mirror write before commit reads it
+            seq_lens_dev = jnp.asarray(self.cache.seq_lens)
+            # repro: allow[RPR105] spec round is host-synchronous; no mirror write before commit reads it
+            page_table_dev = jnp.asarray(self.cache.page_table)
+            active_dev = jnp.asarray(active)
+            with t.span(PID_DEVICE, DEVICE_TID, "verify",
+                        width=width, rows=len(decoding)):
+                logits, pools = self.verifier.verify(
+                    self.params, jnp.asarray(tokens), self.cache.pools,
+                    page_table_dev, seq_lens_dev, jnp.asarray(lengths),
+                    active_dev,
+                )
+                out, acc = self.verifier.sample(
+                    logits, jnp.asarray(tokens[:, 1:]), proposal.logits,
+                    self._next_key(), sp, jnp.asarray(lengths), active_dev,
+                )
+                out = np.asarray(out)
+                acc = np.asarray(acc)
+            with t.span(PID_DEVICE, DEVICE_TID, "commit"):
+                if self.verifier.needs_state_commit:
+                    commit_lengths = np.where(active, acc + 1, 0).astype(np.int32)
+                    pools = self.verifier.commit_state(
+                        self.params, jnp.asarray(tokens), pools,
+                        page_table_dev, seq_lens_dev,
+                        jnp.asarray(commit_lengths), active_dev,
+                    )
+                jax.block_until_ready(pools)
+            dt = time.perf_counter() - t0
         self._c_decode_s.inc(dt)
         self._h_decode_step.observe(dt)
         self.profiler.record("spec_round", n, dt)

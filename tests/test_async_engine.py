@@ -53,10 +53,10 @@ _LENS = (5, 11, 7, 9)
 _GENS = (6, 3, 8, 5)
 
 
-def _run(model, params, prompts, gens, **cfg_kw):
+def _run(model, params, prompts, gens, tracer=None, **cfg_kw):
     kw = dict(num_slots=2, page_size=4, max_seq_len=24, prefill_bucket=8)
     kw.update(cfg_kw)
-    server = Server(model, params, ServerConfig(**kw))
+    server = Server(model, params, ServerConfig(**kw), tracer=tracer)
     reqs = [server.submit(p, max_new_tokens=g)
             for p, g in zip(prompts, gens)]
     results = server.run()
@@ -227,15 +227,24 @@ def test_batched_prefill_compile_count_bounded(served_model):
 # -- engine observability -----------------------------------------------------
 
 def test_engine_metrics(served_model):
-    """engine_inflight settles to 0 and engine_idle_seconds observes one
-    wait per harvested step."""
+    """engine_inflight settles to 0 and every harvested step records one
+    ``harvest.wait`` span (the host's wait at the stream boundary), of the
+    step's kind."""
+    from repro.obs import JsonTracer
+
     cfg, model, params = served_model
     prompts = _prompts(cfg, (5, 7), seed=8)
-    server, _ = _run(model, params, prompts, (4, 4), async_depth=2)
+    server, _ = _run(model, params, prompts, (4, 4), tracer=JsonTracer(),
+                     async_depth=2)
     snap = server.metrics.snapshot()
     assert snap["gauges"]["engine_inflight"] == 0
-    idle = snap["histograms"]["engine_idle_seconds"]
-    assert idle["count"] > 0
+    assert "engine_idle_seconds" not in snap["histograms"]
+    events = server.tracer.events
+    harvested = [e["name"].removesuffix(".complete") for e in events
+                 if e["ph"] == "X"]
+    waits = [e["args"]["kind"] for e in events
+             if e["ph"] == "B" and e["name"] == "harvest.wait"]
+    assert harvested and waits == harvested
 
 
 # -- spec interaction ---------------------------------------------------------

@@ -287,6 +287,45 @@ def test_null_tracer_is_inert():
     t.reset()  # no state to clear, no error
 
 
+@pytest.mark.parametrize("tracer", [NullTracer, JsonTracer])
+def test_span_annotates_the_profiler_and_records_when_enabled(monkeypatch,
+                                                               tracer):
+    """``span`` always enters a ``TraceAnnotation`` with its name and args
+    (the profiler's sink); only an enabled tracer also records B/E."""
+    entered = []
+
+    class Annotation:
+        def __init__(self, name, **args):
+            self.name, self.args = name, args
+
+        def __enter__(self):
+            entered.append(("enter", self.name, self.args))
+
+        def __exit__(self, *exc):
+            entered.append(("exit", self.name, {}))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    t = tracer()
+    with t.span(PID_DEVICE, DEVICE_TID, "decode.dispatch", decoding=2, ctx=9):
+        with t.span(PID_DEVICE, DEVICE_TID, "harvest.wait", kind="decode"):
+            pass
+    assert entered == [
+        ("enter", "decode.dispatch", {"decoding": 2, "ctx": 9}),
+        ("enter", "harvest.wait", {"kind": "decode"}),
+        ("exit", "harvest.wait", {}),
+        ("exit", "decode.dispatch", {}),
+    ]
+    if tracer is NullTracer:
+        return
+    assert [(e["ph"], e["name"], e.get("args")) for e in t.events
+            if e["ph"] in "BE"] == [
+        ("B", "decode.dispatch", {"decoding": 2, "ctx": 9}),
+        ("B", "harvest.wait", {"kind": "decode"}),
+        ("E", "harvest.wait", None),
+        ("E", "decode.dispatch", None),
+    ]
+
+
 # -- instrumented server ------------------------------------------------------
 
 _LENS = (5, 11, 7, 9)
@@ -338,6 +377,80 @@ def test_server_trace_passes_validator_with_full_chains(served_model, tmp_path):
     inflight = {e["name"] for e in events
                 if e["pid"] == PID_DEVICE and e["ph"] == "X"}
     assert {"prefill_chunk.complete", "decode.complete"} <= inflight
+
+
+def test_profiler_trace_holds_the_step_spans(served_model, tmp_path):
+    """Under ``jax.profiler`` (the default NullTracer), a server's steps show
+    in the profiler's own trace: ``server.step`` holding ``server.admit``,
+    the dispatches, ``harvest.wait`` and ``server.commit``. Each
+    ``decode.dispatch`` carries the rows it decodes and their contexts
+    summed, new tokens included, as read from the engine's page mirrors
+    at dispatch (the number a benchmark computes from the same mirrors)."""
+    from jax.profiler import ProfileData
+
+    cfg, model, params = served_model
+    server = Server(model, params, ServerConfig(
+        num_slots=2, page_size=4, max_seq_len=24, prefill_bucket=8,
+        prefill_chunk=4))
+    eng, seen = server.engine, []
+    dispatch_decode = eng.dispatch_decode
+
+    def decode(*, active, **kw):
+        seen.append((int(active.sum()),
+                     int((server.cache.seq_lens[active] + 1).sum())))
+        return dispatch_decode(active=active, **kw)
+
+    eng.dispatch_decode = decode
+    prompts = _prompts(cfg, _LENS)
+    with jax.profiler.trace(str(tmp_path)):
+        for p, g in zip(prompts, _GENS):
+            server.submit(p, max_new_tokens=g)
+        server.run()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    spans = [ev for plane in ProfileData.from_file(str(path)).planes
+             if plane.name == "/host:CPU" for line in plane.lines
+             for ev in line.events]
+    by_name = {}
+    for ev in spans:
+        by_name.setdefault(ev.name, []).append(ev)
+    assert {"server.step", "server.admit", "prefill_chunk.dispatch",
+            "decode.dispatch", "harvest.wait", "server.commit"} <= set(by_name)
+    decodes = sorted(by_name["decode.dispatch"], key=lambda ev: ev.start_ns)
+    assert [(dict(ev.stats)["decoding"], dict(ev.stats)["ctx"])
+            for ev in decodes] == seen
+    assert {dict(ev.stats)["kind"] for ev in by_name["harvest.wait"]} \
+        == {"prefill_chunk", "decode"}
+
+    def inside(ev):
+        return any(s.start_ns <= ev.start_ns and ev.end_ns <= s.end_ns
+                   for s in by_name["server.step"])
+
+    assert all(inside(ev) for ev in decodes + by_name["harvest.wait"])
+
+
+def test_step_programs_name_their_layers(served_model):
+    """The compiled decode step names each layer's ops with a named scope
+    (in op metadata only: the program keeps its name), and so does the
+    sampler."""
+    import re
+
+    _, model, params = served_model
+    server = Server(model, params, ServerConfig(
+        num_slots=2, page_size=4, max_seq_len=24))
+    eng, cache = server.engine, server.cache
+    hlo = eng._decode.lower(
+        params, eng._last_tok, cache.pools, cache.page_table,
+        cache.seq_lens, np.ones(2, bool)).compile().as_text()
+    assert hlo.startswith("HloModule jit_decode_step")
+    stacks = re.findall(r'op_name="jit\(decode_step\)/([^"]*)"', hlo)
+    layers = {p for s in stacks for p in s.split("/")}
+    assert {"embed", "attn", "ffn", "lm_head"} <= layers
+    logits = np.zeros((2, model.cfg.vocab_size), np.float32)
+    hlo = eng._sample.lower(logits, jax.random.PRNGKey(0), temperature=np.zeros(2, np.float32),
+                            top_k=np.zeros(2, np.int32), top_p=np.ones(2, np.float32)
+                            ).compile().as_text()
+    assert hlo.startswith("HloModule jit_sample_logits")
+    assert 'op_name="jit(sample_logits)/sample/' in hlo
 
 
 def test_metrics_ttft_percentiles_within_one_bucket(served_model):
