@@ -7,15 +7,23 @@ sliced back. See ``semiring.pad_value_for`` discussion + docs/DESIGN.md Sec. 3 (
 gating has no TPU analogue; padding-waste is the software observable).
 
 Batching: ``gemm_op`` accepts arbitrary leading batch dims on x (and
-optionally on w / y, broadcast-compatible). On the Pallas path the flattened
-batch becomes the kernel's outer grid axis; an unbatched w stays 2D and is
-shared across the batch (linear layers never replicate weights). Block sizes
-default to the selection layer in ``repro.kernels.tuning`` (heuristic table,
-env override, optional disk-cached autotune) instead of a hardcoded 128^3.
+optionally on w / y, broadcast-compatible). On the Pallas path a weight
+shared by the whole batch (2D, or batch dims all 1) folds the batch rows of
+x (and of y) into M: B products of M rows become one product of B*M rows,
+so the kernel fetches and widens each weight tile once per call, not once
+per batch element. The fold is exact for every GEMM-Op: an output row
+depends only on its own rows of x and y and keeps its own accumulation, in
+the same K order for a given tile. Only a batched w (attention products,
+MoE experts, xLSTM) keeps the flattened batch as the kernel's outer grid
+axis. Block sizes default to the selection layer in
+``repro.kernels.tuning`` (heuristic table, env override, optional
+disk-cached autotune), resolved from the rows each kernel call sees
+(``kernel_rows``), split over the data axes of an ambient mesh.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -180,9 +188,7 @@ def _pallas_gemm_op(
 ):
     m, kdim = x.shape[-2:]
     n = w.shape[-1]
-    batch_x, batch_w = x.shape[:-2], w.shape[:-2]
-    batch_y = () if y is None else y.shape[:-2]
-    out_batch = np.broadcast_shapes(batch_x, batch_w, batch_y)
+    out_batch = _out_batch(x, w, y)
 
     # Quantize operands to the storage grid before padding so pad values are
     # exactly representable and the kernel sees true storage dtypes. Callers
@@ -197,24 +203,21 @@ def _pallas_gemm_op(
         # (matches the XLA path and the oracle — no pre-round of Y).
         y = y.astype(policy.acc)
 
-    w_shared = w.ndim == 2 or all(d == 1 for d in batch_w)
-    if w_shared:
-        w3 = w.reshape(w.shape[-2:])
-        x3 = jnp.broadcast_to(x, out_batch + (m, kdim))
+    if _shares_weight(w):
+        # One (B*M, K) @ (K, N) call: the batch rows fold into M.
+        w3 = w.reshape(kdim, n)
+        x3 = jnp.broadcast_to(x, out_batch + (m, kdim)).reshape(-1, kdim)
+        y3 = None
+        if y is not None:
+            y3 = jnp.broadcast_to(y, out_batch + (m, n)).reshape(-1, n)
     else:
-        w3 = jnp.broadcast_to(w, out_batch + (kdim, n))
-        w3 = w3.reshape((-1, kdim, n))
-        x3 = jnp.broadcast_to(x, out_batch + (m, kdim))
-    if out_batch:
-        x3 = x3.reshape((-1, m, kdim))
-
-    y3 = y
-    if y is not None and y.ndim > 2 and any(d != 1 for d in y.shape[:-2]):
-        y3 = jnp.broadcast_to(y, out_batch + (m, n))
-        if out_batch:
-            y3 = y3.reshape((-1, m, n))
-    elif y is not None:
-        y3 = y.reshape(y.shape[-2:])
+        w3 = jnp.broadcast_to(w, out_batch + (kdim, n)).reshape(-1, kdim, n)
+        x3 = jnp.broadcast_to(x, out_batch + (m, kdim)).reshape(-1, m, kdim)
+        y3 = y
+        if y is not None and any(d != 1 for d in y.shape[:-2]):
+            y3 = jnp.broadcast_to(y, out_batch + (m, n)).reshape(-1, m, n)
+        elif y is not None:
+            y3 = y.reshape(m, n)  # broadcast over the batch by the kernel
 
     def run(x3, w3, y3=None):
         x3, w3, y3, (mo, no) = _pad_operands(x3, w3, y3, gop, bm, bn, bk)
@@ -227,11 +230,34 @@ def _pallas_gemm_op(
         return z[..., :mo, :no]
 
     operands = (x3, w3) if y3 is None else (x3, w3, y3)
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh.size > 1 and not mesh.are_all_axes_manual:
+    mesh = _ambient_mesh()
+    if mesh is not None:
         run = _shard_over_mesh(run, mesh, operands)
     z = run(*operands)
     return z.reshape(out_batch + (m, n))
+
+
+def _ambient_mesh():
+    """The mesh a GEMM is traced under, where the kernel must be sharded
+    by hand (see ``_shard_over_mesh``); None on one device or inside a
+    ``shard_map`` body."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.size > 1 and not mesh.are_all_axes_manual:
+        return mesh
+    return None
+
+
+def _row_split(mesh, m: int, batch: int | None):
+    """(batch axes, M axes, data-parallel size): the data axes (every axis
+    but ``model``) split the kernel's batch axis where it divides evenly,
+    else its M rows where they do. ``batch`` is None for a 2D x."""
+    dp = tuple(a for a in mesh.axis_names if a != "model")
+    n_dp = math.prod(mesh.shape[a] for a in dp)
+    if dp and batch is not None and batch % n_dp == 0:
+        return dp, None, n_dp
+    if dp and m % n_dp == 0:
+        return None, dp, n_dp
+    return None, None, n_dp
 
 
 def _shard_over_mesh(run, mesh, operands):
@@ -245,18 +271,12 @@ def _shard_over_mesh(run, mesh, operands):
     accumulation, exactly as on one device.
     """
     x, w = operands[:2]
-    dp = tuple(a for a in mesh.axis_names if a != "model")
-    n_dp = 1
-    for a in dp:
-        n_dp *= mesh.shape[a]
     n_ax = None
     if "model" in mesh.axis_names and w.shape[-1] % mesh.shape["model"] == 0:
         n_ax = "model"
-    b_ax = m_ax = None
-    if dp and x.ndim == 3 and x.shape[0] % n_dp == 0:
-        b_ax = dp
-    elif dp and x.shape[-2] % n_dp == 0:
-        m_ax = dp
+    b_ax, m_ax, _ = _row_split(
+        mesh, x.shape[-2], x.shape[0] if x.ndim == 3 else None
+    )
     batched = (b_ax,) if x.ndim == 3 else ()
     x_spec = P(*batched, m_ax, None)
     w_spec = P(b_ax, None, n_ax) if w.ndim == 3 else P(None, n_ax)
@@ -272,6 +292,38 @@ def _shard_over_mesh(run, mesh, operands):
 # ---------------------------------------------------------------------------
 # Public entry point
 # ---------------------------------------------------------------------------
+
+
+def _out_batch(x, w, y) -> tuple[int, ...]:
+    return np.broadcast_shapes(
+        x.shape[:-2], w.shape[:-2], () if y is None else y.shape[:-2]
+    )
+
+
+def _shares_weight(w) -> bool:
+    """Whether one weight serves the whole batch (2D, or batch dims all 1)."""
+    return all(d == 1 for d in w.shape[:-2])
+
+
+def kernel_rows(x, w, y=None) -> tuple[int | None, int]:
+    """(batch, M) of the Pallas kernel call for these operands: a shared
+    weight folds the batch rows into M and leaves no batch axis (None)."""
+    out_batch = _out_batch(x, w, y)
+    m = x.shape[-2]
+    if _shares_weight(w):
+        return None, math.prod(out_batch) * m
+    return math.prod(out_batch), m
+
+
+def _rows_per_device(x, w, y) -> int:
+    """M of each kernel call: the folded rows, or one device's share of
+    them where an ambient mesh splits M (``_shard_over_mesh``)."""
+    batch, m = kernel_rows(x, w, y)
+    mesh = _ambient_mesh()
+    if mesh is None:
+        return m
+    _, m_ax, n_dp = _row_split(mesh, m, batch)
+    return m // n_dp if m_ax else m
 
 
 @functools.partial(
@@ -325,8 +377,7 @@ def gemm_op(
     x: (..., M, K); w: (K, N) or (..., K, N); y: optional (M, N) / (..., M, N)
     — leading dims broadcast. ``block_* = None`` defers to the tuning layer.
     """
-    m, kdim = x.shape[-2:]
-    n = w.shape[-1]
+    kdim, n = x.shape[-1], w.shape[-1]
     requested = (block_m, block_n, block_k)
     if backend != "xla":
         concrete = not isinstance(x, jax.core.Tracer)
@@ -340,7 +391,8 @@ def gemm_op(
             )
         else:
             block_m, block_n, block_k = tuning.resolve_block_sizes(
-                m, n, kdim, policy=policy, requested=requested
+                _rows_per_device(x, w, y), n, kdim,
+                policy=policy, requested=requested,
             )
     else:
         block_m, block_n, block_k = 0, 0, 0  # unused on the XLA path

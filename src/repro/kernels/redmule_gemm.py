@@ -14,13 +14,16 @@ TPU mapping of the paper's datapath (docs/DESIGN.md Sec. 2):
     paper's FNCOMP CE stage.
 
 Grid: (B, M/bm, N/bn, K/bk) with K innermost and batch as the *outermost*
-grid axis (not ``vmap``-of-``pallas_call``: one launch covers the whole
-batch, so the weight tile for an unbatched ``w`` is streamed once per (i, j)
-and shared across batch steps instead of being replicated per example).
-``w`` and ``y`` may each be unbatched (2D — broadcast over B, the linear
-layer case) or batched (3D, leading dim B). The accumulator initializes from
-Y (the GEMM-Op bias matrix) when present — valid because ``star`` is
-associative and commutative, so folding Y in first equals combining it last.
+grid axis (one launch covers the whole batch, not ``vmap``-of-
+``pallas_call``). ``w`` and ``y`` may each be unbatched (2D, broadcast over
+B) or batched (3D, leading dim B). The weight's block index (kk, j) changes
+on every grid step, so an unbatched ``w`` is fetched from HBM, and widened
+in VMEM, once per batch step: B products of M rows cost B passes over the
+weight. ``ops.gemm_op`` therefore folds the batch into M whenever the weight
+is shared, so there the batch axis carries batched weights only (attention
+products, MoE experts, xLSTM). The accumulator initializes from Y (the
+GEMM-Op bias matrix) when present — valid because ``star`` is associative
+and commutative, so folding Y in first equals combining it last.
 """
 from __future__ import annotations
 

@@ -315,12 +315,9 @@ def autotune_block_sizes(
 
     from repro.kernels import ops as kernel_ops  # local: avoid import cycle
 
-    m, k = x.shape[-2], x.shape[-1]
-    n = w.shape[-1]
-    batch = 1
-    for d in x.shape[:-2]:
-        batch *= d
-    key = f"{backend}/{policy.name}/{gop.name}/{batch}x{m}x{n}x{k}"
+    batch, m = kernel_ops.kernel_rows(x, w, y)
+    k, n = x.shape[-1], w.shape[-1]
+    key = f"{backend}/{policy.name}/{gop.name}/{batch or 1}x{m}x{n}x{k}"
     path = cache_path or default_cache_path()
     cache = _load_cache(path)
     if key in cache:

@@ -428,3 +428,104 @@ def test_default_blocks_used_when_unspecified(rng):
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(x) @ np.asarray(w), rtol=1e-5, atol=1e-5
     )
+
+
+# -- shared-weight batch rows fold into M ------------------------------------
+
+
+def _redmule_calls(jaxpr):
+    """(weight shape, grid) of every ``redmule_gemm`` kernel call in a
+    (Closed)Jaxpr, nested programs (scans, custom VJPs, pjit) included."""
+    from jax.extend import core as jcore
+
+    out = []
+
+    def walk(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call" and eqn.params["name"] == "redmule_gemm":
+                out.append((eqn.invars[1].aval.shape, eqn.params["grid_mapping"].grid))
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, (list, tuple)) else (v,):
+                    if isinstance(sub, jcore.ClosedJaxpr):
+                        walk(sub.jaxpr)
+                    elif isinstance(sub, jcore.Jaxpr):
+                        walk(sub)
+
+    walk(getattr(jaxpr, "jaxpr", jaxpr))
+    return out
+
+
+# (name, x batch, M rows per element, K, N, w kind, y kind). Decode rows are
+# (slots, 1, K); K=1280 needs K padding against the 1024-deep E4M3 tile
+# (as ``down``'s 12800 does); N=300 is ragged like the 49155-token vocab.
+FOLD_CASES = [
+    ("decode", 8, 1, 512, 256, "shared", None),
+    ("decode_y", 8, 1, 512, 256, "shared", "batched"),
+    ("ragged_k", 8, 1, 1280, 256, "shared", None),
+    ("ragged_k_y", 8, 1, 1280, 256, "shared", "batched"),
+    ("ragged_n", 8, 1, 256, 300, "shared", None),
+    ("ragged_n_y", 8, 1, 256, 300, "shared", "batched"),
+    ("verify_rows", 4, 3, 256, 200, "shared", None),
+    ("w_batch_ones", 8, 1, 256, 200, "ones", "batched"),
+    ("y_2d_broadcast", 4, 1, 256, 200, "shared", "2d"),
+    ("y_row_broadcast", 4, 3, 256, 200, "shared", "row"),
+    # Only a batched weight keeps the batch as the kernel's grid axis.
+    ("batched_w", 3, 5, 256, 200, "batched", None),
+]
+
+
+@pytest.mark.parametrize("gop", [semiring.MATMUL, semiring.ALL_PAIRS_SHORTEST_PATH],
+                         ids=lambda g: g.name)
+@pytest.mark.parametrize("case", FOLD_CASES, ids=[c[0] for c in FOLD_CASES])
+def test_shared_weight_fold_matches_per_element_calls(case, gop, rng):
+    """A shared-weight GEMM over a batch of x is one kernel call of B*M
+    rows, bitwise equal to B separate M-row calls (each row keeps its own
+    fp32 accumulation in the same K order), whatever the batch shape of y;
+    a batched w keeps the batch as the kernel's outer grid axis, also equal
+    per element."""
+    _, b, m, k, n, w_kind, y_kind = case
+    x = jnp.asarray(rng.standard_normal((b, m, k)).astype(np.float32))
+    w_shape = {"shared": (k, n), "ones": (1, k, n), "batched": (b, k, n)}[w_kind]
+    w = jnp.asarray(rng.standard_normal(w_shape).astype(np.float32))
+    y_shape = {None: None, "batched": (b, m, n), "2d": (m, n), "row": (b, 1, n)}[y_kind]
+    y = None if y_shape is None else jnp.asarray(
+        rng.standard_normal(y_shape).astype(np.float32))
+
+    def gemm(x_, w_, y_):
+        return ops.gemm_op(x_, w_, y_, gop=gop, policy=TPU_HFP8,
+                           backend="pallas_interpret")
+
+    got = gemm(x, w, y)
+    assert got.shape == (b, m, n)
+    for i in range(b):
+        w_i = w[i] if w_kind == "batched" else w.reshape(k, n)
+        y_i = None if y is None else (y if y_kind == "2d" else y[i])
+        np.testing.assert_array_equal(
+            np.asarray(got[i], np.float32), np.asarray(gemm(x[i], w_i, y_i), np.float32),
+            err_msg=f"batch element {i}",
+        )
+    [(w_seen, grid)] = _redmule_calls(jax.make_jaxpr(gemm)(x, w, y))
+    if w_kind == "batched":
+        assert grid[0] == b
+    else:  # one M tile, chosen for the folded rows, covers them all
+        assert len(w_seen) == 2 and grid[:2] == (1, 1)
+
+
+@pytest.mark.parametrize("step", ["decode", "verify", "prefill_batch"])
+def test_serving_steps_fetch_each_shared_weight_once(step):
+    """Engagement counter: in the decode, verify and batched-prefill steps
+    of a small decoder on the Pallas backend, no ``redmule_gemm`` call with
+    a shared (2D) weight carries a batch grid axis (each one used to run
+    the weight once per slot: q, k, v, o, gate, up, down and the lm_head)."""
+    from repro.analysis import contracts
+
+    cfg, model = contracts._build_model(
+        "granite-3-8b", backend="pallas_interpret", fp8_kv=False, smoke=True)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pools = jax.eval_shape(lambda: model.init_state_store(
+        contracts.NUM_SLOTS, contracts.NUM_PAGES, contracts.PAGE_SIZE))
+    fn, args, _ = contracts._step_inputs(model, params, pools, cfg.vocab_size)[step]
+    calls = _redmule_calls(jax.make_jaxpr(fn)(*args))
+    shared = [grid for w_shape, grid in calls if len(w_shape) == 2]
+    assert len(shared) >= 8, calls  # 7 in the layer body, 1 in the lm_head
+    assert [g for g in shared if g[0] > 1] == []

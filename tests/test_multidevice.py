@@ -118,6 +118,58 @@ def test_pallas_gemm_under_mesh_runs_per_device():
     """, n_dev=4)
 
 
+def test_folded_decode_gemm_under_mesh_tiles_per_shard():
+    """A shared-weight decode GEMM, (8 slots, 1, K) @ (K, N), folds its
+    slots into M; under a data mesh those rows split over the devices as
+    M, and each device's kernel takes its tile from its own rows (2 of 8
+    on a 4-way data axis, 4 of 8 under 2x2), not from all 8. The numbers
+    equal the one-device call exactly."""
+    _run("""
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.extend import core as jcore
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core.precision import TPU_HFP8
+    from repro.kernels import ops
+    from repro.launch.mesh import make_mesh
+
+    kx, kw = jax.random.split(jax.random.PRNGKey(0))
+    x = jax.random.normal(kx, (8, 1, 1280), jnp.float32)
+    w = jax.random.normal(kw, (1280, 384), jnp.float32)
+    gemm = lambda x, w: ops.gemm_op(x, w, policy=TPU_HFP8,
+                                    backend="pallas_interpret")
+    want = np.asarray(jax.jit(gemm)(x, w), np.float32)
+
+    def kernel_x_rows(jaxpr):
+        rows = []
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                rows.append(eqn.invars[0].aval.shape[-2])
+            for v in eqn.params.values():
+                if isinstance(v, jcore.ClosedJaxpr):
+                    rows += kernel_x_rows(v.jaxpr)
+                elif isinstance(v, jcore.Jaxpr):
+                    rows += kernel_x_rows(v)
+        return rows
+
+    for shape, names, rows in [((4,), ("data",), 2),
+                               ((2, 2), ("data", "model"), 4)]:
+        mesh = make_mesh(shape, names)
+
+        def sharded(x, w):
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                return gemm(x, w)
+
+        jaxpr = jax.make_jaxpr(sharded)(x, w)
+        assert "shard_map" in str(jaxpr)
+        assert kernel_x_rows(jaxpr.jaxpr) == [rows], (names, kernel_x_rows(jaxpr.jaxpr))
+        on_mesh = NamedSharding(mesh, P())
+        got = jax.jit(sharded)(jax.device_put(x, on_mesh), jax.device_put(w, on_mesh))
+        got = np.asarray(got, np.float32)
+        np.testing.assert_array_equal(got, want)
+    print("OK")
+    """, n_dev=4)
+
+
 def test_compressed_psum_error_feedback():
     """fp8-compressed gradient all-reduce converges to the true mean via
     error feedback (bias shrinks across repeated reductions)."""

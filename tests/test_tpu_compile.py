@@ -27,14 +27,16 @@ from repro.kernels import ops, tuning
 D_MODEL, D_FF, VOCAB = 4096, 12800, 49155
 N_HEADS, N_KV_HEADS, HEAD_DIM = 32, 8, 128
 SLOTS, PAGE_SIZE, PAGES_PER_SLOT = 8, 16, 128
-# (band, M, N): one GEMM per band of kernels/tuning.py, shaped as the
-# serving and training steps call it.
+# (band, M, K, N): one GEMM per band of kernels/tuning.py, shaped as the
+# serving and training steps call it. Decode's `down` pads K to the
+# 1024-deep E4M3 tile.
 GEMM_BANDS = [
-    ("decode", 8, VOCAB),
-    ("verify", 5, D_MODEL),
-    ("chunk", 32, D_FF),
-    ("batched", 256, D_MODEL),
-    ("training", 2048, D_FF),
+    ("decode", 8, D_MODEL, VOCAB),
+    ("verify", 5, D_MODEL, D_MODEL),
+    ("chunk", 32, D_MODEL, D_FF),
+    ("batched", 256, D_MODEL, D_MODEL),
+    ("training", 2048, D_MODEL, D_FF),
+    ("decode_down", 8, D_FF, D_MODEL),
 ]
 
 
@@ -75,11 +77,11 @@ def _kernel_calls(compiled, name: str) -> int:
 
 
 @pytest.mark.parametrize("policy", ["tpu_bf16", "tpu_hfp8"])
-@pytest.mark.parametrize("band,m,n", GEMM_BANDS, ids=[b[0] for b in GEMM_BANDS])
-def test_gemm_band_compiles(one_chip, band, m, n, policy):
+@pytest.mark.parametrize("band,m,k,n", GEMM_BANDS, ids=[b[0] for b in GEMM_BANDS])
+def test_gemm_band_compiles(one_chip, band, m, k, n, policy):
     pol = get_policy(policy)
-    x = _spec((m, D_MODEL), jnp.bfloat16, one_chip)
-    w = _spec((D_MODEL, n), jnp.bfloat16, one_chip)
+    x = _spec((m, k), jnp.bfloat16, one_chip)
+    w = _spec((k, n), jnp.bfloat16, one_chip)
     f = jax.jit(lambda x, w: ops.gemm_op(x, w, policy=pol, backend="pallas"))
     compiled = f.lower(x, w).compile()
     assert _kernel_calls(compiled, "redmule_gemm") == 1
@@ -143,3 +145,20 @@ def test_gemm_under_mesh_compiles(topo):
     # x split four ways, w two ways over "model": bf16 bytes per device.
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert per_device == (2 * 1024 * D_MODEL // 4 + D_MODEL * D_FF // 2) * 2
+
+
+def test_folded_decode_under_mesh_compiles(topo):
+    """Under a 2x2 (data, model) mesh decode's folded rows split over
+    "data" as M: each device's kernel takes a 4-row tile of E4M3 rows."""
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    pol = get_policy("tpu_hfp8")
+    x = _spec((SLOTS, 1, D_MODEL), jnp.bfloat16, NamedSharding(mesh, P()))
+    w = _spec((D_MODEL, D_FF), jnp.bfloat16, NamedSharding(mesh, P(None, "model")))
+
+    def f(x, w):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return ops.gemm_op(x, w, policy=pol, backend="pallas")
+
+    compiled = jax.jit(f).lower(x, w).compile()
+    assert _kernel_calls(compiled, "redmule_gemm") == 1
