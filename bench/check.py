@@ -1,6 +1,21 @@
 """The comparison that decides ``correct``: what the timed path produced,
 against the plain float32 reference in ``reference/``.
 
+A configuration file names its reference module with ``"reference":
+"<module>"``, the file ``bench/reference/<module>.py`` of the run's checkout
+(``decoder`` where the key is absent); a new architecture adds its own file.
+The module imports nothing of the program and provides:
+
+- ``Dims.from_config(conf)``: the widths the reference runs, read from the
+  configuration file's published keys (never from ``program``), hashable;
+- ``logits_at(dims, key, tokens, rows, cols, quant=None)``: float32 logits
+  (N, V) at positions (rows[i], cols[i]) of the token rows (B, S), with
+  weights drawn from ``key`` (``jax.random.PRNGKey(seed)``) as the program's
+  ``model.init`` draws them, rounded to the served storage type and held in
+  float32, every product at ``Precision.HIGHEST``, one layer's weights at a
+  time so that it fits on the chip the program has left. ``quant`` names
+  the control's lower precision (``"int4"``), computed in place of float32.
+
 Served cells: for a sample of the requests the window finished, the
 reference runs once over each prompt with its served tokens. A served token
 is the program's greedy choice; its gap is how far the reference's logit of
@@ -18,6 +33,7 @@ import os
 
 import numpy as np
 
+import common
 from common import ROOT, BenchError, load_json
 
 PAD = 128  # rows of the reference batch are padded to a multiple of this
@@ -47,20 +63,21 @@ def served_positions(seqs):
     return tokens, np.array(rows), np.array(cols), np.array(served)
 
 
-def serve_gaps(conf: dict, seed: int, seqs, control: str | None = None) -> np.ndarray:
-    """Gap of every served token below the reference's best logit. With
-    ``control``, the tokens judged are those the reference computed at that
-    precision puts first at the same positions (the control's reading)."""
+def serve_gaps(conf: dict, seed: int, seqs, control: str | None = None,
+               root: str = ROOT) -> np.ndarray:
+    """Gap of every served token below the reference's best logit, by the
+    configuration's reference module under ``root``. With ``control``, the
+    tokens judged are those the reference computed at that precision puts
+    first at the same positions (the control's reading)."""
     import jax
 
-    from reference import decoder
-
-    dims = decoder.Dims.from_config(conf)
+    ref_mod = common.reference(conf, root)
+    dims = ref_mod.Dims.from_config(conf)
     key = jax.random.PRNGKey(seed)
     tokens, rows, cols, served = served_positions(seqs)
-    ref = np.asarray(decoder.logits_at(dims, key, tokens, rows, cols))
+    ref = np.asarray(ref_mod.logits_at(dims, key, tokens, rows, cols))
     if control is not None:
-        low = np.asarray(decoder.logits_at(dims, key, tokens, rows, cols, quant=control))
+        low = np.asarray(ref_mod.logits_at(dims, key, tokens, rows, cols, quant=control))
         served = low.argmax(-1)
     return ref.max(-1) - ref[np.arange(len(served)), served]
 

@@ -7,6 +7,7 @@ import importlib.util
 import json
 import logging
 import os
+import re
 import sys
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
@@ -27,17 +28,26 @@ def spec(root: str = ROOT) -> dict:
 
 
 def cell(bench: dict, name: str, root: str = ROOT) -> dict:
-    """The workload entry, its configuration file and its traffic file."""
+    """The workload entry, its configuration file, its traffic file and the
+    checkout ``root`` they were found in. A configuration that names no
+    reference file, or a program field ``ModelConfig`` lacks, is refused
+    here, before set-up."""
+    import program
+
     work = {w["name"]: w for w in bench["workloads"]}
     if name not in work:
         raise BenchError(f"no workload {name!r}; known: {sorted(work)}")
     w = work[name]
-    conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    entry = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    conf = load_json(os.path.join(root, entry["file"]))
+    reference_path(conf, root)
+    program.model_config(conf)
     return {
         "workload": w,
-        "config": load_json(os.path.join(root, conf["file"])),
+        "config": conf,
         "traffic": load_json(os.path.join(root, "bench", "traffic", w["traffic"] + ".json")),
         "metrics": metrics_for(bench, name),
+        "root": root,
     }
 
 
@@ -60,6 +70,32 @@ def reader(metric: str, root: str = ROOT):
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
     return mod.read
+
+
+def reference_path(conf: dict, root: str = ROOT) -> str:
+    """The file of the configuration's plain reference:
+    ``bench/reference/<name>.py`` for its ``reference`` key, ``decoder``
+    where it has none."""
+    name = conf.get("reference", "decoder")
+    path = os.path.join(root, "bench", "reference", f"{name}.py")
+    if not re.fullmatch(r"[A-Za-z_][A-Za-z0-9_]*", name) or not os.path.exists(path):
+        raise BenchError(f"configuration {conf.get('name')!r} names reference {name!r}, "
+                         f"and there is no {path}")
+    return path
+
+
+def reference(conf: dict, root: str = ROOT):
+    """The configuration's reference module (see ``check.py``), loaded from
+    its file once per process."""
+    path = reference_path(conf, root)
+    name = f"bench_reference:{path}"
+    if name not in sys.modules:
+        mod_spec = importlib.util.spec_from_file_location(name, path)
+        # Registered before it runs, as an import does: a dataclass looks
+        # its module up by name.
+        sys.modules[name] = importlib.util.module_from_spec(mod_spec)
+        mod_spec.loader.exec_module(sys.modules[name])
+    return sys.modules[name]
 
 
 def peaks(device_kind: str) -> dict:

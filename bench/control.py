@@ -35,8 +35,8 @@ def readings(cell: dict, seed: int, seconds: float, devices, compiles) -> dict:
     run.window(seconds, None)
     run.free()
     sample = run.sample()
-    prog = check.serve_gaps(cell["config"], seed, sample)
-    ctrl = check.serve_gaps(cell["config"], seed, sample, control=CONTROL)
+    prog = check.serve_gaps(cell["config"], seed, sample, root=cell["root"])
+    ctrl = check.serve_gaps(cell["config"], seed, sample, control=CONTROL, root=cell["root"])
     return {"seed": seed, "tokens": int(len(prog)), "requests": len(sample),
             "program": float(prog.max()), "control": float(ctrl.max()),
             "program_mean": float(prog.mean()), "control_mean": float(ctrl.mean())}

@@ -58,7 +58,7 @@ class Dims:
             layers=c["num_hidden_layers"], d_model=c["hidden_size"],
             n_heads=c["num_attention_heads"],
             n_kv_heads=c["num_key_value_heads"],
-            head_dim=c["hidden_size"] // c["num_attention_heads"],
+            head_dim=c.get("head_dim", c["hidden_size"] // c["num_attention_heads"]),
             d_ff=c["intermediate_size"], vocab=c["vocab_size"],
             rope_theta=float(c["rope_theta"]),
             eps=float(c.get("as_run", {}).get("rms_norm_eps", c["rms_norm_eps"])),

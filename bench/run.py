@@ -5,9 +5,11 @@
 
 The cell is an entry of ``BENCHMARK.json``'s ``workloads``; its
 configuration file, its traffic mix (``bench/traffic/<mix>.json``), its
-metric readers (``bench/metrics/<metric>.py``) and its limits
-(``bench/checks/<cell>.json``) are found by name. Set-up builds the program
-and warms every shape the window uses; the window then runs for
+metric readers (``bench/metrics/<metric>.py``), its limits
+(``bench/checks/<cell>.json``) and the reference its configuration names
+(``bench/reference/<module>.py``, see ``check.py``) are found by name.
+Set-up builds the program and warms every shape the window uses; the window
+then runs for
 ``--seconds``; after it, the program's state is freed and the plain
 reference checks what the window produced.
 
@@ -90,14 +92,20 @@ def run(args, *, require_chip: bool = True, root: str = common.ROOT) -> dict:
                    traffic=cell["traffic"], chips=n_chips, compiles_in_window=in_window)
         if trace_dir:
             import reduce
+            import scopes
             from jax.profiler import ProfileData
 
             files = [os.path.join(d, f) for d, _, fs in os.walk(trace_dir)
                      for f in fs if f.endswith(".xplane.pb")]
             if not files:
                 raise BenchError("the traced run wrote no trace")
-            rec["trace"] = reduce.reduce(ProfileData.from_file(files[0]), n_chips,
-                                         cell_run.host_spans)
+            with open(files[0], "rb") as f:
+                data = f.read()
+            profile = ProfileData.from_serialized_xspace(data)
+            rec["trace"] = reduce.reduce(profile, n_chips, cell_run.host_spans)
+            # The program's own names: device time per layer scope, host
+            # time per server step.
+            rec["trace"].update(scopes.reduce_trace(data, profile, n_chips))
     finally:
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)

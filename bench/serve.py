@@ -67,6 +67,7 @@ class ServeCell:
 
     def __init__(self, cell: dict, seed: int, devices, log_compiles):
         self.conf, self.mix, self.seed = cell["config"], cell["traffic"], seed
+        self.root = cell["root"]
         self.devices = devices
         self.compiles = log_compiles
 
@@ -232,7 +233,7 @@ class ServeCell:
         import check
 
         sample = self.sample()
-        gaps = check.serve_gaps(self.conf, self.seed, sample)
+        gaps = check.serve_gaps(self.conf, self.seed, sample, root=self.root)
         log(f"check: {len(gaps)} served tokens of {len(sample)} requests compared")
         return {"logit_gap": float(gaps.max()), "logit_gap_mean": float(gaps.mean())}
 

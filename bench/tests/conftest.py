@@ -3,7 +3,8 @@
 They run on the CPU at smoke sizes: the harness's modules and the program
 are put on the path, and each test builds a small checkout of its own with
 ``make_root``: a ``BENCHMARK.json``, a configuration file, a traffic mix,
-the limits and the metric readers, all found by name as on the chip.
+the limits, the metric readers and the reference modules, all found by name
+as on the chip.
 """
 from __future__ import annotations
 
@@ -52,16 +53,19 @@ def fp32_config(**program) -> dict:
 
 
 def make_root(tmp, name: str, conf: dict, mix: dict, limits: dict, e2e: list,
-              per_layer: list = (), extra_metrics: dict | None = None) -> str:
-    """A checkout under ``tmp`` holding one cell ``name``."""
+              per_layer: list = (), extra_metrics: dict | None = None,
+              extra_references: dict | None = None) -> str:
+    """A checkout under ``tmp`` holding one cell ``name``, the repository's
+    metric readers and reference modules, and any extra ones (name: source)."""
     root = str(tmp)
     for d in ("configs", "traffic", "checks"):
         os.makedirs(os.path.join(root, "bench", d), exist_ok=True)
-    shutil.copytree(os.path.join(BENCH, "metrics"), os.path.join(root, "bench", "metrics"),
-                    dirs_exist_ok=True)
-    for metric, source in (extra_metrics or {}).items():
-        with open(os.path.join(root, "bench", "metrics", metric + ".py"), "w") as f:
-            f.write(source)
+    for d, extra in (("metrics", extra_metrics), ("reference", extra_references)):
+        shutil.copytree(os.path.join(BENCH, d), os.path.join(root, "bench", d),
+                        dirs_exist_ok=True, ignore=shutil.ignore_patterns("__pycache__"))
+        for module, source in (extra or {}).items():
+            with open(os.path.join(root, "bench", d, module + ".py"), "w") as f:
+                f.write(source)
     with open(os.path.join(root, "bench", "configs", "tiny.json"), "w") as f:
         json.dump(conf, f)
     with open(os.path.join(root, "bench", "traffic", "mix.json"), "w") as f:

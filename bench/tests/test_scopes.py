@@ -181,9 +181,11 @@ def test_new_readers_return_numbers(chat):
                          + ["setup_s"])
 def test_existing_metrics_read_the_same(chat, metric):
     """The per-layer split and the program's spans added to a record move
-    none of the metrics the benchmark already reads."""
+    none of the metrics the benchmark reads without them, and the
+    program's spans among the host names move none of those that read the
+    split."""
     data, profile, _, side = chat
-    plain = _record(data, profile, side, with_scopes=False)
+    plain = _record(data, profile, side, with_scopes=metric in NEW)
     wired = _record(data, profile, side, serve.HOST_SPANS + PROGRAM_SPANS)
     read = common.reader(metric)
     assert read(wired) == read(plain)
@@ -210,7 +212,7 @@ def test_record_trace_at_smoke_size(tmp_path, monkeypatch):
     monkeypatch.setattr(record_trace, "LEAD_S", 1.0)
     monkeypatch.setattr(record_trace, "RATE", 6.0)
     cell = {"workload": {"name": "tiny.chat", "chips": 1}, "traffic": dict(CHAT),
-            "config": tiny_config("granite-3-8b-serve", backend="xla")}
+            "config": tiny_config("granite-3-8b-serve", backend="xla"), "root": common.ROOT}
     out = str(tmp_path / "t.xplane.pb.gz")
     args = record_trace.parse(["--seconds", "1", "--out", out])
     side = record_trace.record(cell, args, jax.devices()[:1], common.CompileLog(), out)
@@ -220,3 +222,36 @@ def test_record_trace_at_smoke_size(tmp_path, monkeypatch):
         profile = ProfileData.from_serialized_xspace(f.read())
     assert {n for n, _, _ in reduce.host_spans(profile, set(PROGRAM_SPANS))} >= {
         "server.step", "decode.dispatch", "harvest.wait"}
+
+
+def test_traced_run_records_the_programs_names(chat, tmp_path, monkeypatch):
+    """A traced ``run.run`` on the CPU puts ``scopes.reduce_trace``'s
+    ``scopes`` and ``host_steps`` into its record, and the four readers of
+    the program's names report from them. The CPU's profiler writes no
+    device plane, so the trace the run reads is ``chat6s``, written where
+    the profiler writes its own."""
+    import jax
+
+    from conftest import CHAT as MIX, make_root, run_cell, tiny_config
+
+    data, profile, _, side = chat
+    traced = {}
+
+    def stop_trace():
+        out = os.path.join(traced["dir"], "plugins", "profile", "chip")
+        os.makedirs(out)
+        with open(os.path.join(out, "chip.xplane.pb"), "wb") as f:
+            f.write(data)
+
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: traced.update(dir=d))
+    monkeypatch.setattr(jax.profiler, "stop_trace", stop_trace)
+    per_layer = [m for m in common.spec()["per_layer"] if m["name"] in NEW]
+    e2e = [{"name": "itl_p95_ms", "unit": "ms", "better": "lower", "bound": 0.05,
+            "source": "host_clock"}]
+    root = make_root(tmp_path, "tiny.chat", tiny_config("granite-3-8b-serve", backend="xla"),
+                     MIX, {"logit_gap": {"limit": 10.0}}, e2e, per_layer)
+    result = run_cell(root, "tiny.chat", trace=1)
+    assert traced and set(result["metrics"]) == set(NEW)
+    want = _record(data, profile, side)
+    for metric in NEW:
+        assert result["metrics"][metric]["value"] == common.reader(metric)(want)

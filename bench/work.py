@@ -40,7 +40,7 @@ class Widths:
         return cls(
             layers=conf["num_hidden_layers"], d=conf["hidden_size"],
             heads=conf["num_attention_heads"], kv_heads=conf["num_key_value_heads"],
-            head_dim=conf["hidden_size"] // conf["num_attention_heads"],
+            head_dim=conf.get("head_dim", conf["hidden_size"] // conf["num_attention_heads"]),
             ff=conf["intermediate_size"], vocab=conf["vocab_size"],
             experts=conf.get("num_local_experts", 0),
             top_k=conf.get("num_experts_per_tok", 0),
