@@ -17,6 +17,7 @@ from repro.engine.engine import (
     BACKENDS,
     DEFAULT_ENGINE,
     Engine,
+    LayerWeight,
     ambient_engine,
     as_engine,
     current_engine,
@@ -28,6 +29,7 @@ __all__ = [
     "BACKENDS",
     "DEFAULT_ENGINE",
     "Engine",
+    "LayerWeight",
     "ambient_engine",
     "as_engine",
     "closure",

@@ -34,7 +34,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +49,28 @@ def _check_backend(name: str) -> str:
     if name not in BACKENDS:
         raise ValueError(f"unknown backend {name!r}; expected one of {BACKENDS}")
     return name
+
+
+class LayerWeight(NamedTuple):
+    """One layer's GEMM weight, left in the stack of every layer's.
+
+    A serving step scans its stacked layers; a weight the scan sliced out
+    would be copied out of the stack before each kernel call. Handed to
+    :meth:`Engine.linear` as ``LayerWeight(stack, index)``, the kernel
+    reads the layer's tiles in place. Forward only: serving steps take no
+    gradients.
+    """
+
+    stack: jnp.ndarray  # (L, K, N)
+    index: jnp.ndarray  # () int32
+
+    @property
+    def shape(self) -> tuple[int, ...]:
+        return self.stack.shape[1:]
+
+    @property
+    def dtype(self):
+        return self.stack.dtype
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,13 +131,41 @@ class Engine:
         broadcast-batched (..., K, N)."""
         return _autodiff.mp_matmul(a, b, self)
 
-    def linear(self, x: jnp.ndarray, w: jnp.ndarray,
+    def linear(self, x: jnp.ndarray, w: jnp.ndarray | LayerWeight,
                b: jnp.ndarray | None = None) -> jnp.ndarray:
-        """y = x @ w (+ b) through the engine. x: (..., K), w: (K, N)."""
-        y = self.matmul(x, w)
+        """y = x @ w (+ b) through the engine. x: (..., K), w: (K, N), or
+        a :class:`LayerWeight` in the forward storage format, read in
+        place: the same operands and kernel call as :meth:`matmul`'s
+        forward, not differentiable."""
+        if not isinstance(w, LayerWeight):
+            y = self.matmul(x, w)
+        else:
+            pol = self.policy
+            if jnp.dtype(w.dtype) != jnp.dtype(pol.storage_fwd):
+                raise ValueError(
+                    f"a LayerWeight is read as stored: {w.dtype} is not the "
+                    f"{pol.name} forward storage format {pol.storage_fwd}")
+            y = _kernel_ops.gemm_op(
+                x.astype(pol.compute).astype(pol.storage_fwd), w.stack,
+                policy=pol, block_m=self.block_m, block_n=self.block_n,
+                block_k=self.block_k, backend=self.backend,
+                operand_quant=False, layer=w.index,
+            )
         if b is not None:
             y = y + b.astype(y.dtype)
         return y
+
+    def weight_prep_bytes(self, x: jnp.ndarray, w, *,
+                          relaid: bool = False) -> int:
+        """Bytes a product of x with the weight ``w`` moves to bring w into
+        the form this engine's kernel reads (``kernels.ops``); 0 for a
+        weight stored that way."""
+        if isinstance(w, LayerWeight):
+            w = jax.ShapeDtypeStruct(w.shape, w.dtype)
+        return _kernel_ops.weight_prep_bytes(
+            x, w, policy=self.policy, backend=self.backend,
+            blocks=self.blocks, relaid=relaid,
+        )
 
     def gemm_op(self, x: jnp.ndarray, w: jnp.ndarray,
                 y: jnp.ndarray | None = None,
@@ -218,3 +268,4 @@ def engine_scope(engine: Engine):
 # must not import this module at module scope (no cycle).
 from repro.engine import autodiff as _autodiff  # noqa: E402
 from repro.engine.closure import closure as _closure_fn  # noqa: E402
+from repro.kernels import ops as _kernel_ops  # noqa: E402

@@ -15,7 +15,10 @@ per batch element. The fold is exact for every GEMM-Op: an output row
 depends only on its own rows of x and y and keeps its own accumulation, in
 the same K order for a given tile. Only a batched w (attention products,
 MoE experts, xLSTM) keeps the flattened batch as the kernel's outer grid
-axis. Block sizes default to the selection layer in
+axis. A ``layer`` index makes w a stack (L, K, N) of shared weights, one per
+layer, of which the product reads one in place (a serving step's scan over
+layers); where the tile would pad it, the layer is sliced out first.
+Block sizes default to the selection layer in
 ``repro.kernels.tuning`` (heuristic table, env override, optional
 disk-cached autotune), resolved from the rows each kernel call sees
 (``kernel_rows``), split over the data axes of an ambient mesh.
@@ -185,10 +188,18 @@ def _reduce(op: Op, prod):
 def _pallas_gemm_op(
     x, w, y, gop: GemmOp, policy: PrecisionPolicy,
     bm: int, bn: int, bk: int, out_dtype, operand_quant: bool, interpret: bool,
+    layer=None,
 ):
     m, kdim = x.shape[-2:]
     n = w.shape[-1]
-    out_batch = _out_batch(x, w, y)
+    if layer is not None and (
+        operand_quant or kdim % bk or n % bn or _ambient_mesh() is not None
+    ):
+        # Read in place only what the kernel takes as it is stored.
+        w, layer = w[layer], None
+    # The layer's weight as a shape: what it is without slicing it out.
+    shared = w if layer is None else jax.ShapeDtypeStruct(w.shape[1:], w.dtype)
+    out_batch = _out_batch(x, shared, y)
 
     # Quantize operands to the storage grid before padding so pad values are
     # exactly representable and the kernel sees true storage dtypes. Callers
@@ -203,9 +214,10 @@ def _pallas_gemm_op(
         # (matches the XLA path and the oracle — no pre-round of Y).
         y = y.astype(policy.acc)
 
-    if _shares_weight(w):
-        # One (B*M, K) @ (K, N) call: the batch rows fold into M.
-        w3 = w.reshape(kdim, n)
+    if layer is not None or _shares_weight(w):
+        # One (B*M, K) @ (K, N) call: the batch rows fold into M. A layer
+        # read in place from its stack is shared by the batch too.
+        w3 = w if layer is not None else w.reshape(kdim, n)
         x3 = jnp.broadcast_to(x, out_batch + (m, kdim)).reshape(-1, kdim)
         y3 = None
         if y is not None:
@@ -220,12 +232,17 @@ def _pallas_gemm_op(
             y3 = y.reshape(m, n)  # broadcast over the batch by the kernel
 
     def run(x3, w3, y3=None):
-        x3, w3, y3, (mo, no) = _pad_operands(x3, w3, y3, gop, bm, bn, bk)
+        if layer is not None:
+            # Only the rows of x (and y) can need padding: K and N divide.
+            x3, _, y3, (mo, no) = _pad_operands(x3, shared, y3, gop, bm, bn, bk)
+        else:
+            x3, w3, y3, (mo, no) = _pad_operands(x3, w3, y3, gop, bm, bn, bk)
         z = redmule_gemm_pallas(
             x3, w3, y3,
             gop=gop, policy=policy,
             block_m=bm, block_n=bn, block_k=bk,
             out_dtype=out_dtype, interpret=interpret,
+            w_layer=None if layer is None else jnp.reshape(layer, (1,)),
         )
         return z[..., :mo, :no]
 
@@ -315,6 +332,31 @@ def kernel_rows(x, w, y=None) -> tuple[int | None, int]:
     return math.prod(out_batch), m
 
 
+def weight_prep_bytes(x, w, *, policy: PrecisionPolicy, backend: str,
+                      blocks=(None, None, None), relaid: bool = False) -> int:
+    """Bytes a product of x with the weight ``w`` moves to bring w into the
+    form the kernel reads: a cast to the policy's forward storage format,
+    and on the Pallas backends a pad of K or N to the tile; ``relaid``
+    marks a transpose or relayout its caller made. Counted as w's bytes
+    read plus the prepared operand's bytes written; 0 where w is stored as
+    the kernel takes it."""
+    k, n = w.shape[-2:]
+    kp, np_ = k, n
+    if backend != "xla":
+        shared = jax.ShapeDtypeStruct((k, n), w.dtype)
+        _, bn, bk = tuning.resolve_block_sizes(
+            _rows_per_device(x, shared, None), n, k,
+            policy=policy, requested=tuple(blocks),
+        )
+        kp, np_ = _ceil_to(k, bk), _ceil_to(n, bn)
+    cast = jnp.dtype(w.dtype) != jnp.dtype(policy.storage_fwd)
+    if not (cast or relaid or (kp, np_) != (k, n)):
+        return 0
+    batch = math.prod(w.shape[:-2])
+    written = batch * kp * np_ * jnp.dtype(policy.storage_fwd).itemsize
+    return batch * k * n * jnp.dtype(w.dtype).itemsize + written
+
+
 def _rows_per_device(x, w, y) -> int:
     """M of each kernel call: the folded rows, or one device's share of
     them where an ambient mesh splits M (``_shard_over_mesh``)."""
@@ -340,13 +382,15 @@ def _rows_per_device(x, w, y) -> int:
     ),
 )
 def _gemm_op_impl(
-    x, w, y, *,
+    x, w, y, layer, *,
     gop: GemmOp, policy: PrecisionPolicy,
     block_m: int, block_n: int, block_k: int,
     backend: str, out_dtype, operand_quant: bool,
 ):
     out_dtype = policy.out if out_dtype is None else out_dtype
     if backend == "xla":
+        if layer is not None:
+            w = w[layer]
         return _xla_gemm_op(x, w, y, gop, policy, out_dtype, operand_quant)
     if backend not in ("pallas", "pallas_interpret"):
         raise ValueError(
@@ -354,7 +398,7 @@ def _gemm_op_impl(
         )
     return _pallas_gemm_op(
         x, w, y, gop, policy, block_m, block_n, block_k, out_dtype,
-        operand_quant, interpret=backend == "pallas_interpret",
+        operand_quant, interpret=backend == "pallas_interpret", layer=layer,
     )
 
 
@@ -371,18 +415,23 @@ def gemm_op(
     backend: str = "xla",  # xla | pallas | pallas_interpret
     out_dtype=None,
     operand_quant: bool = True,
+    layer: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Public GEMM-Op entry point: Z = star(Y, star_k(circ(X, W))).
 
     x: (..., M, K); w: (K, N) or (..., K, N); y: optional (M, N) / (..., M, N)
-    — leading dims broadcast. ``block_* = None`` defers to the tuning layer.
+    — leading dims broadcast. With ``layer`` (a () int32), w is a stack
+    (L, K, N) and the product reads w[layer], shared by x's batch (see the
+    module docstring). ``block_* = None`` defers to the tuning layer.
     """
     kdim, n = x.shape[-1], w.shape[-1]
+    shared = w if layer is None else jax.ShapeDtypeStruct(w.shape[1:], w.dtype)
     requested = (block_m, block_n, block_k)
     if backend != "xla":
         concrete = not isinstance(x, jax.core.Tracer)
         if (
             concrete
+            and layer is None
             and tuning.autotune_enabled()
             and all(b is None for b in requested)
         ):
@@ -391,13 +440,13 @@ def gemm_op(
             )
         else:
             block_m, block_n, block_k = tuning.resolve_block_sizes(
-                _rows_per_device(x, w, y), n, kdim,
+                _rows_per_device(x, shared, y), n, kdim,
                 policy=policy, requested=requested,
             )
     else:
         block_m, block_n, block_k = 0, 0, 0  # unused on the XLA path
     return _gemm_op_impl(
-        x, w, y,
+        x, w, y, layer,
         gop=gop, policy=policy,
         block_m=block_m, block_n=block_n, block_k=block_k,
         backend=backend, out_dtype=out_dtype, operand_quant=operand_quant,

@@ -24,6 +24,12 @@ is shared, so there the batch axis carries batched weights only (attention
 products, MoE experts, xLSTM). The accumulator initializes from Y (the
 GEMM-Op bias matrix) when present — valid because ``star`` is associative
 and commutative, so folding Y in first equals combining it last.
+
+A serving step may hand the kernel one layer's weight as the whole stack of
+every layer's (L, K, N) plus the layer's index, scalar-prefetched: the
+weight's block index becomes (layer, kk, j), so the kernel reads the
+layer's tiles in place, where a slice taken outside it would be copied
+out of the stack on every call.
 """
 from __future__ import annotations
 
@@ -122,13 +128,16 @@ def redmule_gemm_pallas(
     block_k: int = 128,
     out_dtype=None,
     interpret: bool = False,
+    w_layer: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Tiled GEMM-Op. Shapes must already be padded to block multiples.
 
     x: (M, K) or (B, M, K); w: (K, N) or (B, K, N); y: optional (M, N) or
     (B, M, N) — all in a storage dtype (fp8/fp16/bf16/fp32). Unbatched w/y
-    broadcast over B. Returns x's rank with trailing (M, N), in ``out_dtype``
-    (default ``policy.out``).
+    broadcast over B. With ``w_layer`` (a (1,) int32 array), w is a stack
+    (L, K, N) of which the product reads layer ``w_layer[0]``, unbatched.
+    Returns x's rank with trailing (M, N), in ``out_dtype`` (default
+    ``policy.out``).
     """
     squeeze = x.ndim == 2
     if squeeze:
@@ -137,7 +146,9 @@ def redmule_gemm_pallas(
     k2, n = w.shape[-2:]
     if k != k2:
         raise ValueError(f"inner dims disagree: x {x.shape} @ w {w.shape}")
-    if w.ndim != 2 and w.shape[0] != b:
+    if w_layer is not None and w.ndim != 3:
+        raise ValueError(f"a layer index needs a stacked w (L, K, N), got {w.shape}")
+    if w.ndim != 2 and w_layer is None and w.shape[0] != b:
         raise ValueError(f"batched w leading dim mismatch: x {x.shape} @ w {w.shape}")
     if m % block_m or n % block_n or k % block_k:
         raise ValueError(
@@ -155,26 +166,32 @@ def redmule_gemm_pallas(
         compute_dtype=policy.compute,
         acc_dtype=policy.acc,
     )
+    # Index maps take ``*_``: with a layer index it is prefetched and passed
+    # to every map after the grid indices.
     in_specs = [
-        pl.BlockSpec((1, block_m, block_k), lambda bb, i, j, kk: (bb, i, kk)),
+        pl.BlockSpec((1, block_m, block_k), lambda bb, i, j, kk, *_: (bb, i, kk)),
     ]
-    if w.ndim == 3:
+    if w_layer is not None:
+        in_specs.append(pl.BlockSpec(
+            (None, block_k, block_n), lambda bb, i, j, kk, layer: (layer[0], kk, j)
+        ))
+    elif w.ndim == 3:
         in_specs.append(
-            pl.BlockSpec((1, block_k, block_n), lambda bb, i, j, kk: (bb, kk, j))
+            pl.BlockSpec((1, block_k, block_n), lambda bb, i, j, kk, *_: (bb, kk, j))
         )
     else:
         in_specs.append(
-            pl.BlockSpec((block_k, block_n), lambda bb, i, j, kk: (kk, j))
+            pl.BlockSpec((block_k, block_n), lambda bb, i, j, kk, *_: (kk, j))
         )
     operands = [x, w]
     if y is not None:
         if y.ndim == 3:
-            in_specs.append(
-                pl.BlockSpec((1, block_m, block_n), lambda bb, i, j, kk: (bb, i, j))
-            )
+            in_specs.append(pl.BlockSpec(
+                (1, block_m, block_n), lambda bb, i, j, kk, *_: (bb, i, j)
+            ))
         else:
             in_specs.append(
-                pl.BlockSpec((block_m, block_n), lambda bb, i, j, kk: (i, j))
+                pl.BlockSpec((block_m, block_n), lambda bb, i, j, kk, *_: (i, j))
             )
         operands.append(y)
         body = kernel
@@ -183,13 +200,23 @@ def redmule_gemm_pallas(
             x_ref, w_ref, None, o_ref, acc_ref
         )
 
-    out = pl.pallas_call(
-        body,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=0 if w_layer is None else 1,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_m, block_n), lambda bb, i, j, kk: (bb, i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, m, n), out_dtype),
+        out_specs=pl.BlockSpec(
+            (1, block_m, block_n), lambda bb, i, j, kk, *_: (bb, i, j)
+        ),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), policy.acc)],
+    )
+    if w_layer is not None:
+        operands.insert(0, w_layer)
+        in_place = body
+        body = lambda layer_ref, *refs: in_place(*refs)  # noqa: E731
+    out = pl.pallas_call(
+        body,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, m, n), out_dtype),
         interpret=interpret,
         name="redmule_gemm",
     )(*operands)

@@ -180,6 +180,19 @@ def _fit_vmem(bm: int, bn: int, bk: int, itemsize: int) -> int:
     return bk
 
 
+def _dividing_depth(bk: int, k: int, fits) -> int:
+    """The K tile of lane multiples nearest to ``bk`` that divides K rounded
+    up to the lane (``bk`` itself where it does; ties go to the deeper),
+    among the depths ``fits`` admits. A decode or verify row reads each
+    weight once per step, so a tile that leaves no K remainder spares the
+    step a padded copy of the weight (``down``'s K 12800 against a
+    1024-deep tile)."""
+    lanes = _ceil_to(k, LANE) // LANE
+    depths = [d * LANE for d in range(1, lanes + 1)
+              if lanes % d == 0 and (d == 1 or fits(d * LANE))]
+    return min(depths, key=lambda d: (abs(d - bk), -d))
+
+
 def clamp_blocks(
     bm: int, bn: int, bk: int, m: int, n: int, k: int, itemsize: int = 4
 ) -> tuple[int, int, int]:
@@ -216,20 +229,14 @@ def heuristic_block_sizes(
         # mode accepts sub-sublane tiles, real-TPU re-tunes override this
         # via the autotune cache). K tile deepens into the freed VMEM.
         bk, bn = _SKINNY_HEURISTIC.get(itemsize, (512, 128))
-        bm = m
-        bk = _fit_vmem(bm, bn, bk, itemsize)
-        _, bn, bk = clamp_blocks(bm, bn, bk, m, n, k, itemsize)
-        return bm, _ceil_to(bn, LANE), _ceil_to(bk, sub)
+        return _exact_m_blocks(m, n, k, bk, bn, itemsize)
     if m <= _VERIFY_M:
         # Speculative-verify table: block_m == M exactly (same sub-sublane
         # rationale as the skinny table — a verify row is k+1 real tokens,
         # and a 32-row fp8 tile would be half padding at k=15), with a K
         # tile between the skinny and chunk depths.
         bk, bn = _VERIFY_HEURISTIC.get(itemsize, (384, 128))
-        bm = m
-        bk = _fit_vmem(bm, bn, bk, itemsize)
-        _, bn, bk = clamp_blocks(bm, bn, bk, m, n, k, itemsize)
-        return bm, _ceil_to(bn, LANE), _ceil_to(bk, sub)
+        return _exact_m_blocks(m, n, k, bk, bn, itemsize)
     if m <= _CHUNK_M:
         # Chunk-prefill table: M tile = the chunk rounded to the sublane
         # grid, K tile deepened into the VMEM a 128-row tile would waste.
@@ -255,6 +262,20 @@ def heuristic_block_sizes(
     # Round auto tiles up to the sublane/lane grid (still <= the caps above,
     # which are sublane/lane multiples themselves).
     return _ceil_to(bm, sub), _ceil_to(bn, LANE), _ceil_to(bk, sub)
+
+
+def _exact_m_blocks(m: int, n: int, k: int, bk: int, bn: int,
+                    itemsize: int) -> tuple[int, int, int]:
+    """Tiles of the decode and verify tables: block_m == M, and the K tile
+    nearest the table's depth that divides the lane-rounded K and fits the
+    VMEM budget."""
+    sub = SUBLANE.get(itemsize, 8)
+    bk = _dividing_depth(
+        _fit_vmem(m, bn, bk, itemsize), k,
+        lambda d: _vmem_bytes(m, bn, d, itemsize) <= _VMEM_BUDGET_BYTES,
+    )
+    _, bn, bk = clamp_blocks(m, bn, bk, m, n, k, itemsize)
+    return m, _ceil_to(bn, LANE), _ceil_to(bk, sub)
 
 
 def _env_blocks() -> tuple[int | None, int | None, int | None]:

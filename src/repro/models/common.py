@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.engine import Engine, as_engine
+from repro.obs import count_weight_prep
 
 Params = dict[str, Any]
 
@@ -25,7 +26,17 @@ def dense_init(key, d_in: int, d_out: int, dtype=jnp.bfloat16, scale: float | No
 
 
 def dense_apply(p: Params, x, engine: Engine):
-    return as_engine(engine).linear(x, p["w"], p.get("b"))
+    return weight_apply(x, p["w"], engine, p.get("b"))
+
+
+def weight_apply(x, w, engine: Engine, b=None, *, relaid: bool = False):
+    """x @ w (+ b) for a GEMM weight ``w`` (a parameter, or an
+    ``engine.LayerWeight``). Where the traced step must prepare w for the
+    kernel (a cast or pad the engine needs, or the transpose or relayout
+    its caller made: ``relaid``), the call is counted (``repro.obs``)."""
+    engine = as_engine(engine)
+    count_weight_prep(engine.weight_prep_bytes(x, w, relaid=relaid))
+    return engine.linear(x, w, b)
 
 
 def norm_init(d: int, kind: str = "rmsnorm", dtype=jnp.float32):
@@ -104,5 +115,12 @@ def embed_apply(p: Params, tokens):
 
 
 def unembed_apply(p: Params, x, engine: Engine):
-    """Tied unembedding: logits = x @ table.T through the engine."""
-    return as_engine(engine).matmul(x, p["table"].T)
+    """Tied unembedding: logits = x @ table.T through the engine.
+
+    A table prepared for serving (``Transformer.serving_params``) carries
+    the product's weight as ``unembed``: transposed already, in the
+    kernel's storage format, its vocabulary zero-padded to the lane. The
+    padded logits are cut off here, so no padded id can be sampled."""
+    if "unembed" in p:
+        return weight_apply(x, p["unembed"], engine)[..., : p["table"].shape[0]]
+    return weight_apply(x, p["table"].T, engine, relaid=True)

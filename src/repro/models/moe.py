@@ -64,11 +64,11 @@ def _router(params, x2, cfg: MoEConfig):
 
 
 def _expert_ffn(up_w, gate_w, down_w, x, cfg: MoEConfig, engine):
-    h = engine.matmul(x, up_w)
-    g = engine.matmul(x, gate_w)
+    h = common.weight_apply(x, up_w, engine)
+    g = common.weight_apply(x, gate_w, engine)
     h = (jax.nn.silu(g.astype(jnp.float32)).astype(h.dtype) * h
          if cfg.act == "swiglu" else common.gelu(g) * h)
-    return engine.matmul(h, down_w)
+    return common.weight_apply(h, down_w, engine)
 
 
 def apply_dense(params, x, cfg: MoEConfig, engine: Engine):
@@ -82,11 +82,14 @@ def apply_dense(params, x, cfg: MoEConfig, engine: Engine):
         jax.nn.one_hot(top_i, e, dtype=jnp.float32) * top_p[..., None], axis=1
     )
     # All experts as one wide GEMM: (T, d) @ (d, E*f).
-    up_all = engine.matmul(x2, params["up"].transpose(1, 0, 2).reshape(d, e * f))
-    gate_all = engine.matmul(x2, params["gate"].transpose(1, 0, 2).reshape(d, e * f))
+    up_all = common.weight_apply(
+        x2, params["up"].transpose(1, 0, 2).reshape(d, e * f), engine, relaid=True)
+    gate_all = common.weight_apply(
+        x2, params["gate"].transpose(1, 0, 2).reshape(d, e * f), engine, relaid=True)
     h = jax.nn.silu(gate_all.astype(jnp.float32)).astype(up_all.dtype) * up_all
     h = h.reshape(-1, e, f) * gates[..., None].astype(h.dtype)
-    y = engine.matmul(h.reshape(-1, e * f), params["down"].reshape(e * f, d))
+    y = common.weight_apply(
+        h.reshape(-1, e * f), params["down"].reshape(e * f, d), engine)
     return y.reshape(b, s, d), aux
 
 

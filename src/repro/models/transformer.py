@@ -21,9 +21,11 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.precision import as_dtype
-from repro.engine import Engine, as_engine
+from repro.engine import Engine, LayerWeight, as_engine
+from repro.kernels.tuning import LANE
 from repro.models import attention, common, ffn, moe, rglru, xlstm
 from repro.models.attention import AttnConfig
+from repro.obs import WeightPrep, count_weight_prep, weight_prep_tally
 
 Params = dict[str, Any]
 
@@ -44,6 +46,26 @@ class CBProfile(NamedTuple):
     needs_kv_pages: bool
     kv_window: int | None
     has_state_rows: bool = False
+
+
+def _keys(path) -> tuple:
+    return tuple(getattr(k, "key", None) for k in path)
+
+
+def _dense_weight(path) -> bool:
+    """A dense layer's ``w``, which ``common.dense_apply`` feeds to the
+    engine; the MoE router's ``w`` goes to ``jnp.matmul`` in float32."""
+    keys = _keys(path)
+    return keys[-1] == "w" and keys[-2:-1] != ("router",)
+
+
+def _gemm_weight(path) -> bool:
+    """A leaf the engine reads as a GEMM weight: a dense ``w``, or an MoE
+    layer's expert stacks."""
+    keys = _keys(path)
+    return _dense_weight(path) or (
+        keys[-2:-1] == ("moe",) and keys[-1] in ("up", "gate", "down")
+    )
 
 
 def _row_mask(mask, leaf):
@@ -219,6 +241,79 @@ class Transformer:
             p["enc_proj"] = common.dense_init(keys[5], cfg.d_model, cfg.d_model, self.dtype)
         return p
 
+    def serving_params(self, params, engine: Engine | None = None) -> Params:
+        """Params for serving: every GEMM weight in the form the engine's
+        kernel reads, prepared once here instead of in every step.
+
+        Where the policy's forward storage format is narrower than a GEMM
+        weight's dtype, the weight is cast to it, and a tied embedding
+        gains ``unembed``: the table transposed to (d_model, vocab), cast,
+        and zero-padded on the vocabulary to the 128 lane
+        (``common.unembed_apply`` cuts the logits back). ``table`` stays
+        for the lookup. One jitted program builds the new leaves; the
+        others are the caller's. Where nothing is narrower (``tpu_bf16``,
+        ``fp32``, weights already in E4M3) the input is returned as it is.
+        The cast is the one each call would make, so a step reads the same
+        operands. Training never calls this: its weights change every step.
+        """
+        eng = as_engine(engine) if engine is not None else self.engine
+        store = jnp.dtype(eng.policy.storage_fwd)
+
+        def narrower(a) -> bool:
+            return (jnp.issubdtype(a.dtype, jnp.floating)
+                    and jnp.dtype(a.dtype).itemsize > store.itemsize)
+
+        flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+        if not flat:
+            return params
+        cast = [i for i, (path, a) in enumerate(flat)
+                if _gemm_weight(path) and narrower(a)]
+        embed = params.get("embed", {})
+        tie = (self.cfg.tie_embeddings and "unembed" not in embed
+               and narrower(embed["table"]))
+        if not cast and not tie:
+            return params
+        vocab = self.cfg.vocab_size
+
+        def prepare(weights, table):
+            weights = [w.astype(store) for w in weights]
+            if table is None:
+                return weights, None
+            pad = -vocab % LANE
+            return weights, jnp.pad(table.T.astype(store), ((0, 0), (0, pad)))
+
+        weights, unembed = jax.jit(prepare)(
+            [flat[i][1] for i in cast], embed["table"] if tie else None)
+        leaves = [a for _, a in flat]
+        for i, w in zip(cast, weights):
+            leaves[i] = w
+        out = jax.tree_util.tree_unflatten(treedef, leaves)
+        if tie:
+            out["embed"] = {**out["embed"], "unembed": unembed}
+        return out
+
+    def _in_place(self, units, engine: Engine):
+        """(units to slice per layer, rebuild) for a serving step's scan.
+
+        A stacked dense weight already in the engine's forward storage
+        format stays whole: ``rebuild(unit, i)`` hands it to the layer as
+        ``LayerWeight(stack, i)``, which the kernel reads in place. Sliced
+        by the scan, it would be copied out of the stack before every
+        kernel call."""
+        store = jnp.dtype(engine.policy.storage_fwd)
+        flat, treedef = jax.tree_util.tree_flatten_with_path(units)
+        keep = [_dense_weight(path) and jnp.dtype(a.dtype) == store
+                for path, a in flat]
+        sliced = [None if k else a for (_, a), k in zip(flat, keep)]
+
+        def rebuild(unit, i):
+            return jax.tree_util.tree_unflatten(treedef, [
+                LayerWeight(a, i) if k else u
+                for (_, a), k, u in zip(flat, keep, unit)
+            ])
+
+        return sliced, rebuild
+
     # -- block application ---------------------------------------------------
     def _apply_block(
         self, kind, p, x, positions, engine, *, cache=None, enc_out=None,
@@ -347,20 +442,26 @@ class Transformer:
         """Scan the stacked units, then the remainder blocks."""
         n_units = self.n_units if stack is not None else 0
         aux_total = jnp.zeros((), jnp.float32)
+        # The unit is traced once and runs n_units times: its weight
+        # preparation counts that many times (the last trace's, should the
+        # scan trace it again).
+        unit_prep = WeightPrep()
 
         def unit_apply(x, unit_p, unit_c):
+            nonlocal unit_prep
             new_c = {} if unit_c is not None else None
             aux_sum = jnp.zeros((), jnp.float32)
-            for j, kind in enumerate(self.pattern):
-                x, c, aux = self._apply_block(
-                    kind, unit_p[f"b{j}"], x, positions, engine,
-                    cache=None if unit_c is None else unit_c[f"b{j}"],
-                    enc_out=enc_out, enc_pos=enc_pos, causal=causal,
-                    decode=decode, paged=paged,
-                )
-                if new_c is not None:
-                    new_c[f"b{j}"] = c
-                aux_sum += aux
+            with weight_prep_tally() as unit_prep:
+                for j, kind in enumerate(self.pattern):
+                    x, c, aux = self._apply_block(
+                        kind, unit_p[f"b{j}"], x, positions, engine,
+                        cache=None if unit_c is None else unit_c[f"b{j}"],
+                        enc_out=enc_out, enc_pos=enc_pos, causal=causal,
+                        decode=decode, paged=paged,
+                    )
+                    if new_c is not None:
+                        new_c[f"b{j}"] = c
+                    aux_sum += aux
             return self._constrain(x), new_c, aux_sum
 
         if n_units:
@@ -373,16 +474,22 @@ class Transformer:
                     return (x, aux_acc + aux), None
                 xs = stack["units"]
             else:
+                # A step with a cache serves (no gradients): weights stored
+                # as the kernel reads them are read in place.
+                units, rebuild = self._in_place(stack["units"], engine)
+
                 def body(carry, xs_):
                     x, aux_acc = carry
-                    p, c = xs_
-                    x, new_c, aux = unit_apply(x, p, c)
+                    p, i, c = xs_
+                    x, new_c, aux = unit_apply(x, rebuild(p, i), c)
                     return (x, aux_acc + aux), new_c
-                xs = (stack["units"], units_cache)
+                xs = (units, jnp.arange(n_units, dtype=jnp.int32), units_cache)
 
             if self.cfg.remat == "block":
                 body = jax.checkpoint(body)
             (x, aux_total), new_units_cache = jax.lax.scan(body, (x, aux_total), xs)
+            count_weight_prep(unit_prep.bytes * n_units,
+                              calls=unit_prep.calls * n_units)
         else:
             new_units_cache = cache["units"] if cache is not None else None
 

@@ -7,8 +7,9 @@ package provides request-lifecycle tracing (``trace``: Chrome
 trace-event / JSONL export, Perfetto-loadable), a process-local metrics
 registry with log-bucket latency histograms and Prometheus/JSON export
 (``metrics``), per-jitted-step wall-clock profiling that separates
-compile from steady state (``profiler``), and the flush plumbing
-(``export``). The server is instrumented against the ``Tracer`` protocol
+compile from steady state (``profiler``), a trace-time count of the
+weight casts, transposes and pads each jitted step does (``weight_prep``),
+and the flush plumbing (``export``). The server is instrumented against the ``Tracer`` protocol
 with a zero-overhead ``NullTracer`` default — tracing off costs nothing
 and changes nothing (bitwise, a tested invariant).
 
@@ -37,6 +38,11 @@ from repro.obs.trace import (
     NullTracer,
     Tracer,
 )
+from repro.obs.weight_prep import (
+    WeightPrep,
+    count_weight_prep,
+    weight_prep_tally,
+)
 
 __all__ = [
     "Counter",
@@ -51,9 +57,12 @@ __all__ = [
     "PID_REQUESTS",
     "StepProfiler",
     "Tracer",
+    "WeightPrep",
+    "count_weight_prep",
     "device_capture",
     "log_bounds",
     "metrics_doc",
+    "weight_prep_tally",
     "write_metrics",
     "write_trace",
 ]

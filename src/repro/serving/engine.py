@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import time
 from typing import Any, Optional
 
@@ -58,10 +59,13 @@ from repro.obs import (
     MetricsRegistry,
     NullTracer,
     StepProfiler,
+    WeightPrep,
+    weight_prep_tally,
 )
 from repro.serving.cache import StateStore, copy_kv_page
 from repro.serving.sampling import GREEDY, sample_logits, stack_params
 from repro.training import make_paged_serve_steps
+from repro.training.loop import resolve_engine
 
 # Allowed P values for batched multi-slot prefill. Bucketing the row count
 # (instead of compiling one shape per prefilling-set size) bounds the
@@ -82,6 +86,16 @@ class InflightStep:
     trace_args: dict
 
 
+def serving_params(model, params, engine=None, backend: Optional[str] = None):
+    """``params`` with every GEMM weight prepared once for the engine the
+    serving steps run (``Transformer.serving_params``), and the bytes the
+    preparation made."""
+    prepared = model.serving_params(params, resolve_engine(model, engine, backend))
+    kept = {id(a) for a in jax.tree.leaves(params)}
+    made = sum(a.nbytes for a in jax.tree.leaves(prepared) if id(a) not in kept)
+    return prepared, made
+
+
 class EngineCore:
     """Device-stepping core of the continuous-batching server.
 
@@ -90,6 +104,11 @@ class EngineCore:
     synchronous mode — every step is harvested in the same server
     iteration that dispatched it — and, because dispatch order does not
     depend on depth, greedy outputs are identical at every depth.
+
+    ``params`` are prepared for the steps at construction
+    (:func:`serving_params`); ``weight_prep`` holds, per step kind, the
+    weight casts, transposes and pads the compiled step still does
+    (``repro.obs.weight_prep``), recorded when the step is traced.
     """
 
     def __init__(self, model, params, config, profile, *, engine=None,
@@ -97,7 +116,8 @@ class EngineCore:
                  tracer=None, metrics: Optional[MetricsRegistry] = None,
                  profiler: Optional[StepProfiler] = None):
         self.model = model
-        self.params = params
+        self.params, self.prepared_bytes = serving_params(
+            model, params, engine, backend)
         self.config = config
         self.profile = profile
         self.seed = seed
@@ -110,10 +130,11 @@ class EngineCore:
                 backend=backend,
             )
         )
-        self._prefill_full = jax.jit(prefill_full)
-        self._prefill_chunk = jax.jit(prefill_chunk)
-        self._prefill_batch = jax.jit(prefill_batch)
-        self._decode = jax.jit(decode_step)
+        self.weight_prep: dict[str, WeightPrep] = {}
+        self._prefill_full = self._jit("prefill_full", prefill_full)
+        self._prefill_chunk = self._jit("prefill_chunk", prefill_chunk)
+        self._prefill_batch = self._jit("prefill_batch", prefill_batch)
+        self._decode = self._jit("decode", decode_step)
         self._sample = jax.jit(sample_logits)
         ps = config.page_size
         self._copy_page = jax.jit(
@@ -150,6 +171,17 @@ class EngineCore:
         # NB: the engine is not usable until fresh() builds the StateStore —
         # the Server calls it from _fresh_state so pools are built exactly
         # once per (re)start.
+
+    def _jit(self, kind: str, step):
+        """jit ``step`` under its own name (the program's, which a trace's
+        reader looks up), recording its weight preparation when traced."""
+        @functools.wraps(step)
+        def traced(*args):
+            with weight_prep_tally() as prep:
+                out = step(*args)
+            self.weight_prep[kind] = prep
+            return out
+        return jax.jit(traced)
 
     # -- state lifecycle ---------------------------------------------------
     def fresh(self, pools=None) -> None:

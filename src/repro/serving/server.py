@@ -48,6 +48,7 @@ compile time from steady-state time — reported tok/s never includes tracing.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import time
 from typing import Iterable, NamedTuple, Optional
 
@@ -79,6 +80,8 @@ from repro.serving.spec import (
     Verifier,
     effective_k,
 )
+
+log = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -322,7 +325,6 @@ class Server:
                 "families; use generate_static for this family"
             )
         self.model = model
-        self.params = params
         self.config = config
         self.profile = model.cb_profile()
         # Prefix caching shares KV pages only; a model with recurrent state
@@ -338,6 +340,9 @@ class Server:
             backend=backend, seed=seed, tracer=self.tracer,
             metrics=self.metrics, profiler=self.profiler,
         )
+        # The steps read the weights as the engine's kernel takes them,
+        # prepared once here (the caller's params stay as they were).
+        self.params = self.engine.params
         # Speculative decoding: a drafter (paired model with its own
         # StateStore, or n-gram self-drafting) + the target-side verifier.
         # Passing draft_model without spec enables it at the default k.
@@ -368,6 +373,11 @@ class Server:
                 model, page_size=config.page_size, engine=engine,
                 backend=backend, metrics=self.metrics,
             )
+        log.info(
+            "%s: %d bytes of GEMM weights prepared for serving", model.cfg.name,
+            self.engine.prepared_bytes
+            + getattr(self.drafter, "prepared_bytes", 0),
+        )
         self._fresh_state()
 
     def _bind_metrics(self) -> None:

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.serving.cache import StateStore
+from repro.serving.engine import serving_params
 from repro.serving.sampling import sample_logits, stack_params
 from repro.training import make_paged_serve_steps, make_spec_verify_steps
 
@@ -123,7 +124,8 @@ class ModelDrafter:
                 f"{model.cfg.name}: drafter must be a decoder-only family"
             )
         self.model = model
-        self.params = params
+        self.params, self.prepared_bytes = serving_params(
+            model, params, engine, backend)
         self.k = k
         # A steady-state round replays at most k+1 tokens (accepted prefix
         # + correction); never chunk below that or every round pays two

@@ -250,6 +250,69 @@ def test_ambient_backend_context():
 # -- block-size selection ----------------------------------------------------
 
 
+@pytest.mark.parametrize("m", [1, 2, 4, 8, 9, 12, 16])
+def test_decode_and_verify_k_tile_divides_the_weight(m):
+    """For decode and verify rows (M <= 16) the K tile divides K rounded up
+    to the lane, so a weight whose K is a lane multiple is never padded:
+    ``down``'s K 12800 no longer pads to the 1024-deep tile (13312), and a
+    depth that divides, 1024 for K 4096 under E4M3, stays."""
+    for dt in (jnp.float8_e4m3fn, jnp.bfloat16, jnp.float32):
+        for k in (4096, 12800):
+            _, _, bk = tuning.heuristic_block_sizes(m, 4096, k, dt)
+            assert bk % 128 == 0 and k % bk == 0, (m, dt, k, bk)
+    if m <= 8:
+        assert tuning.heuristic_block_sizes(m, 4096, 4096, jnp.float8_e4m3fn)[2] == 1024
+        assert tuning.heuristic_block_sizes(m, 4096, 12800, jnp.float8_e4m3fn)[2] == 1280
+
+
+def test_decode_down_gemm_pads_nothing():
+    """An (8, 12800) @ (12800, 4096) decode GEMM on an E4M3 weight (``down``
+    at granite-3-8b widths) traces no pad at all: not of w, not of x."""
+    x = jax.ShapeDtypeStruct((8, 12800), jnp.float8_e4m3fn)
+    w = jax.ShapeDtypeStruct((12800, 4096), jnp.float8_e4m3fn)
+    jaxpr = jax.make_jaxpr(lambda x, w: ops.gemm_op(
+        x, w, policy=TPU_HFP8, backend="pallas_interpret", operand_quant=False))(x, w)
+    text = str(jaxpr)
+    assert "redmule_gemm" in text and "pad[" not in text
+
+
+# (name, x shape, K, N): K and N divide the decode tiles (read in place), or
+# K is ragged (the layer is sliced out and padded first).
+LAYER_CASES = [
+    ("in_place", (2, 1, 256), 256, 256),
+    ("in_place_rows", (3, 256), 256, 384),
+    ("ragged_k", (2, 1, 200), 200, 256),
+]
+
+
+@pytest.mark.parametrize("backend", ["pallas_interpret", "xla"])
+@pytest.mark.parametrize("case", LAYER_CASES, ids=[c[0] for c in LAYER_CASES])
+def test_layer_indexed_gemm_matches_its_slice(case, backend, rng):
+    """A product read from one layer of a weight stack (``layer``) equals
+    the product with that layer sliced out, bit for bit; on the Pallas path
+    a stack that needs no pad is handed to the kernel whole, its index
+    prefetched, so no slice of it is taken."""
+    _, x_shape, k, n = case
+    x = jnp.asarray(rng.standard_normal(x_shape).astype(np.float32)).astype(jnp.float8_e4m3fn)
+    w = jnp.asarray(rng.standard_normal((3, k, n)).astype(np.float32)).astype(jnp.float8_e4m3fn)
+
+    def gemm(x_, w_, layer=None):
+        return ops.gemm_op(x_, w_, policy=TPU_HFP8, backend=backend,
+                           operand_quant=False, layer=layer)
+
+    for i in range(3):
+        np.testing.assert_array_equal(
+            np.asarray(gemm(x, w, jnp.int32(i)), np.float32),
+            np.asarray(gemm(x, w[i]), np.float32), err_msg=f"layer {i}")
+    if backend == "pallas_interpret":
+        jaxpr = jax.make_jaxpr(gemm)(x, w, jnp.int32(1))
+        [(w_seen, grid)] = _redmule_calls(jaxpr)
+        in_place = case[0] != "ragged_k"
+        assert grid[0] == 1 and len(w_seen) == 2 and w_seen[1] == n
+        assert (w_seen[0] == k) == in_place  # the sliced layer is padded
+        assert ("dynamic_slice" not in str(jaxpr)) == in_place
+
+
 def test_heuristic_blocks_clamp_to_problem():
     bm, bn, bk = tuning.heuristic_block_sizes(13, 21, 19, jnp.float32)
     assert bm <= 16 and bn == 128 and bk <= 24
@@ -435,7 +498,9 @@ def test_default_blocks_used_when_unspecified(rng):
 
 def _redmule_calls(jaxpr):
     """(weight shape, grid) of every ``redmule_gemm`` kernel call in a
-    (Closed)Jaxpr, nested programs (scans, custom VJPs, pjit) included."""
+    (Closed)Jaxpr, nested programs (scans, custom VJPs, pjit) included. A
+    weight read in place from a stack of layers (its index prefetched, the
+    first operand) reports its layer's (K, N)."""
     from jax.extend import core as jcore
 
     out = []
@@ -443,7 +508,9 @@ def _redmule_calls(jaxpr):
     def walk(j):
         for eqn in j.eqns:
             if eqn.primitive.name == "pallas_call" and eqn.params["name"] == "redmule_gemm":
-                out.append((eqn.invars[1].aval.shape, eqn.params["grid_mapping"].grid))
+                gm = eqn.params["grid_mapping"]
+                w = eqn.invars[1 + gm.num_index_operands].aval.shape
+                out.append((w[1:] if gm.num_index_operands else w, gm.grid))
             for v in eqn.params.values():
                 for sub in v if isinstance(v, (list, tuple)) else (v,):
                     if isinstance(sub, jcore.ClosedJaxpr):
@@ -456,13 +523,13 @@ def _redmule_calls(jaxpr):
 
 
 # (name, x batch, M rows per element, K, N, w kind, y kind). Decode rows are
-# (slots, 1, K); K=1280 needs K padding against the 1024-deep E4M3 tile
-# (as ``down``'s 12800 does); N=300 is ragged like the 49155-token vocab.
+# (slots, 1, K); K=1200 is no multiple of the lane, so the decode table's
+# dividing K tile still pads it; N=300 is ragged like the 49155-token vocab.
 FOLD_CASES = [
     ("decode", 8, 1, 512, 256, "shared", None),
     ("decode_y", 8, 1, 512, 256, "shared", "batched"),
-    ("ragged_k", 8, 1, 1280, 256, "shared", None),
-    ("ragged_k_y", 8, 1, 1280, 256, "shared", "batched"),
+    ("ragged_k", 8, 1, 1200, 256, "shared", None),
+    ("ragged_k_y", 8, 1, 1200, 256, "shared", "batched"),
     ("ragged_n", 8, 1, 256, 300, "shared", None),
     ("ragged_n_y", 8, 1, 256, 300, "shared", "batched"),
     ("verify_rows", 4, 3, 256, 200, "shared", None),

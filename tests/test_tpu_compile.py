@@ -28,8 +28,8 @@ D_MODEL, D_FF, VOCAB = 4096, 12800, 49155
 N_HEADS, N_KV_HEADS, HEAD_DIM = 32, 8, 128
 SLOTS, PAGE_SIZE, PAGES_PER_SLOT = 8, 16, 128
 # (band, M, K, N): one GEMM per band of kernels/tuning.py, shaped as the
-# serving and training steps call it. Decode's `down` pads K to the
-# 1024-deep E4M3 tile.
+# serving and training steps call it. Decode's `down` takes the 1280-deep
+# E4M3 K tile, which divides its K.
 GEMM_BANDS = [
     ("decode", 8, D_MODEL, VOCAB),
     ("verify", 5, D_MODEL, D_MODEL),
@@ -85,6 +85,22 @@ def test_gemm_band_compiles(one_chip, band, m, k, n, policy):
     f = jax.jit(lambda x, w: ops.gemm_op(x, w, policy=pol, backend="pallas"))
     compiled = f.lower(x, w).compile()
     assert _kernel_calls(compiled, "redmule_gemm") == 1
+
+
+@pytest.mark.parametrize("k,n", [(D_MODEL, D_FF), (D_FF, D_MODEL)], ids=["up", "down"])
+def test_layer_indexed_decode_gemm_compiles(one_chip, k, n):
+    """A decode GEMM reading one layer of a 20-layer E4M3 weight stack in
+    place (its index scalar-prefetched): one kernel, and no temporary
+    buffer of the layer's size, so the layer is not copied out."""
+    pol = get_policy("tpu_hfp8")
+    x = _spec((SLOTS, k), jnp.float8_e4m3fn, one_chip)
+    w = _spec((20, k, n), jnp.float8_e4m3fn, one_chip)
+    i = _spec((), jnp.int32, one_chip)
+    f = jax.jit(lambda x, w, i: ops.gemm_op(
+        x, w, policy=pol, backend="pallas", operand_quant=False, layer=i))
+    compiled = f.lower(x, w, i).compile()
+    assert _kernel_calls(compiled, "redmule_gemm") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < k * n
 
 
 def test_hfp8_gemm_vjp_compiles(one_chip):
