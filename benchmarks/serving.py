@@ -82,7 +82,7 @@ def _bench_arch(rows: Rows, arch: str, family: str, smoke: bool) -> dict:
     # -- continuous batching over the StateStore (chunked prefill) ---------
     server = Server(model, params, ServerConfig(
         num_slots=n_slots, page_size=8, max_seq_len=max_seq,
-        prefill_bucket=8, prefill_chunk=_PREFILL_CHUNK,
+        prefill_chunk=_PREFILL_CHUNK,
     ))
     server.warmup([len(p) for p, _ in workload])
     for prompt, gen in workload:
@@ -263,7 +263,7 @@ def _bench_kernel_decode(rows: Rows, smoke: bool) -> dict:
     workload = _workload(n_requests, cfg.vocab_size)
     max_seq = max(len(p) + g for p, g in workload)
     server = Server(model, params, ServerConfig(
-        num_slots=2, page_size=8, max_seq_len=max_seq, prefill_bucket=8,
+        num_slots=2, page_size=8, max_seq_len=max_seq,
         prefill_chunk=_PREFILL_CHUNK,
     ), backend=backend)
     server.warmup([len(p) for p, _ in workload])
@@ -314,7 +314,7 @@ def _bench_spec(rows: Rows, smoke: bool) -> dict:
     def run(spec: bool):
         kw = {"spec": SpecConfig(k=_SPEC_K)} if spec else {}
         server = Server(model, params, ServerConfig(
-            num_slots=1, page_size=8, max_seq_len=max_seq, prefill_bucket=8,
+            num_slots=1, page_size=8, max_seq_len=max_seq, prefill_chunk=8,
         ), **kw)
         # First pass compiles every shape (the k+1-wide verify step has no
         # warmup() coverage); the second, timed pass starts warm.
@@ -450,7 +450,7 @@ def _bench_async_decode(rows: Rows, smoke: bool) -> dict:
 
     def run(depth: int) -> float:
         server = Server(model, params, ServerConfig(
-            num_slots=3, page_size=8, max_seq_len=max_seq, prefill_bucket=8,
+            num_slots=3, page_size=8, max_seq_len=max_seq,
             prefill_chunk=_PREFILL_CHUNK, async_depth=depth,
         ))
         server.warmup([len(p) for p in prompts])

@@ -57,7 +57,7 @@ from repro.models import build  # noqa: E402
 from repro.models.transformer import MeshCtx  # noqa: E402
 from repro.optim import AdamW, cosine_schedule  # noqa: E402
 from repro.serving import Server, ServerConfig  # noqa: E402
-from repro.training import make_paged_serve_steps  # noqa: E402
+from repro.serving.steps import make_paged_serve_steps  # noqa: E402
 
 SEED = 0
 ARCH = "granite-3-8b"
@@ -318,7 +318,7 @@ def agreement(model, params, prompts, num_pages, log: CompileLog):
     """Kernel presence in the compiled steps, then pallas-vs-xla logits."""
     steps = {}
     for backend in (model.engine.backend, "xla"):
-        _, chunk, _, decode = make_paged_serve_steps(
+        chunk, _, decode = make_paged_serve_steps(
             model, page_size=SERVER.page_size,
             engine=model.engine.with_backend(backend))
         steps[backend] = (jax.jit(chunk), jax.jit(decode))

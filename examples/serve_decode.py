@@ -28,7 +28,8 @@ def main():
     ap.add_argument("--num-slots", type=int, default=2)
     ap.add_argument("--fp8-kv", action="store_true",
                     help="store the KV pages in E4M3 (paper fp8 storage)")
-    ap.add_argument("--chunked-prefill", type=int, default=0, metavar="N",
+    ap.add_argument("--chunked-prefill", type=int,
+                    default=ServerConfig.prefill_chunk, metavar="N",
                     help="N-token prefill chunks interleaved with decode")
     args = ap.parse_args()
 
@@ -53,8 +54,8 @@ def main():
 
     server = Server(model, params, ServerConfig(
         num_slots=args.num_slots, page_size=8,
-        max_seq_len=args.prompt_len + args.gen, prefill_bucket=8,
-        prefill_chunk=args.chunked_prefill or None,
+        max_seq_len=args.prompt_len + args.gen,
+        prefill_chunk=args.chunked_prefill,
     ))
     print(f"arch={cfg.name} kv_dtype={cfg.kv_cache_dtype} "
           f"kv pool={server.cache.kv_bytes() / 1e6:.2f} MB "
@@ -63,7 +64,7 @@ def main():
 
     # Mixed lengths, mixed sampling: even requests greedy, odd ones sampled.
     lens = [max(2, args.prompt_len - 3 * (i % 3)) for i in range(args.requests)]
-    server.warmup(lens)  # compile every jitted shape before timing
+    server.warmup(lens)  # compile the chunk and decode shapes before timing
     for i, plen in enumerate(lens):
         sampling = (SamplingParams() if i % 2 == 0
                     else SamplingParams(temperature=0.8, top_k=40, top_p=0.95))

@@ -3,10 +3,9 @@
 The continuous-batching stack leans on invariants that are checkable
 *without running anything* — the software analogues of RedMulE's statically
 provable no-stall schedule. For every decoder-only arch this module
-abstract-traces (``jax.eval_shape`` / ``jax.make_jaxpr``) the four serving
-step kinds the scheduler drives — whole-prompt prefill, chunked prefill,
-batched multi-slot prefill, all-slots decode — plus the speculative verify
-step, against ShapeDtypeStruct stand-ins (no weights, no device memory),
+abstract-traces (``jax.eval_shape`` / ``jax.make_jaxpr``) the three serving
+step kinds the scheduler drives — prefill chunk, batched multi-slot
+prefill, all-slots decode — plus the speculative verify step, against ShapeDtypeStruct stand-ins (no weights, no device memory),
 and asserts:
 
 1. **static shapes** — each step traces at a fixed input signature and
@@ -41,7 +40,7 @@ from repro.configs import ARCH_IDS, get_config
 from repro.models import build
 from repro.serving.cache import _is_kv_leaf
 from repro.serving.engine import P_BUCKETS, EngineCore
-from repro.training import make_paged_serve_steps, make_spec_verify_steps
+from repro.serving.steps import make_paged_serve_steps, make_spec_verify_steps
 
 try:  # jax >= 0.4.36 canonical home; fall back for older trees
     from jax.extend import core as _jcore
@@ -56,14 +55,11 @@ PAGE_SIZE = 8
 PAGES_PER_SLOT = 4
 NUM_PAGES = NUM_SLOTS * PAGES_PER_SLOT + 1  # + the null page
 CHUNK = 8
-FULL_PREFILL = 16
 VERIFY_T = 4  # draft depth k=3 -> k+1 scored positions
 
 # The serving steps the scheduler can drive, with their documented logits
 # contracts (shape is resolved per-arch below).
-STEP_KINDS = (
-    "prefill_full", "prefill_chunk", "prefill_batch", "decode", "verify",
-)
+STEP_KINDS = ("prefill_chunk", "prefill_batch", "decode", "verify")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,9 +161,8 @@ def _build_model(arch: str, *, backend: Optional[str], fp8_kv: bool,
 
 def _step_inputs(model, params, pools, vocab: int):
     """(step_name -> (fn, args, expected_logits_shape)) for one arch."""
-    prefill_full, prefill_chunk, prefill_batch, decode = (
-        make_paged_serve_steps(model, page_size=PAGE_SIZE)
-    )
+    prefill_chunk, prefill_batch, decode = make_paged_serve_steps(
+        model, page_size=PAGE_SIZE)
     verify, _commit = make_spec_verify_steps(model, page_size=PAGE_SIZE)
     i32, b1 = jnp.int32, jnp.bool_
     p_max = EngineCore.allowed_buckets(_BucketProbe(NUM_SLOTS))[-1]
@@ -177,12 +172,6 @@ def _step_inputs(model, params, pools, vocab: int):
     lens = _spec((NUM_SLOTS,), i32)
     act = _spec((NUM_SLOTS,), b1)
     return {
-        "prefill_full": (
-            prefill_full,
-            (params, _spec((1, FULL_PREFILL), i32), pools, row, scalar,
-             scalar, scalar),
-            (1, vocab),
-        ),
         "prefill_chunk": (
             prefill_chunk,
             (params, _spec((1, CHUNK), i32), pools, row, scalar, scalar,

@@ -60,7 +60,7 @@ HOT_PATHS: dict[str, frozenset[str]] = {
     ),
     "repro/serving/sampling.py": frozenset({"filter_logits", "sample_logits"}),
     "repro/models/transformer.py": frozenset(
-        {"prefill_cb", "_prefill_cb_batched", "decode_cb", "verify_cb"}
+        {"prefill_cb", "decode_cb", "verify_cb"}
     ),
 }
 

@@ -22,6 +22,7 @@ from repro.engine.engine import (
     as_engine,
     current_engine,
     engine_scope,
+    resolve_engine,
     set_ambient_engine,
 )
 
@@ -35,5 +36,6 @@ __all__ = [
     "closure",
     "current_engine",
     "engine_scope",
+    "resolve_engine",
     "set_ambient_engine",
 ]

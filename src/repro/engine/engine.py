@@ -243,6 +243,20 @@ def current_engine(default: Engine | None = None) -> Engine:
     return default if default is not None else DEFAULT_ENGINE
 
 
+def resolve_engine(model, engine: Engine | None = None,
+                   backend: str | None = None) -> Engine:
+    """Engine resolution for the step factories: explicit engine > model's
+    configured engine > ambient scope; ``backend`` then overrides the
+    execution backend (the launcher CLI knob)."""
+    if engine is not None:
+        eng = as_engine(engine)
+    else:
+        eng = getattr(model, "engine", None) or current_engine()
+    if backend:
+        eng = eng.with_backend(backend)
+    return eng
+
+
 def set_ambient_engine(engine: Engine | None) -> Engine | None:
     """Set the ambient engine for the current context; returns the previous
     one. Prefer :func:`engine_scope`; this exists for the deprecated

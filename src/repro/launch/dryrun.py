@@ -42,7 +42,8 @@ from repro.models import build, decode_input_specs, train_input_specs  # noqa: E
 from repro.models.transformer import MeshCtx  # noqa: E402
 from repro.optim import AdamW  # noqa: E402
 from repro.roofline import analysis as ra  # noqa: E402
-from repro.training import TrainState, make_serve_steps, make_train_step  # noqa: E402
+from repro.serving.steps import make_serve_steps  # noqa: E402
+from repro.training import TrainState, make_train_step  # noqa: E402
 
 
 def _apply_overrides(cfg, args):

@@ -54,9 +54,10 @@ def main(argv=None):
     ap.add_argument("--page-size", type=int, default=8)
     ap.add_argument("--fp8-kv", action="store_true",
                     help="store the KV pages in E4M3 (paper fp8 storage)")
-    ap.add_argument("--chunked-prefill", type=int, default=0, metavar="N",
+    ap.add_argument("--chunked-prefill", type=int,
+                    default=ServerConfig.prefill_chunk, metavar="N",
                     help="split prompts into N-token chunks interleaved "
-                         "with decode steps (0 = whole-prompt prefill)")
+                         "with decode steps")
     ap.add_argument("--async-depth", type=int, default=0, metavar="D",
                     help="dispatch up to D device steps ahead before the "
                          "host blocks at the stream boundary (0 = "
@@ -64,8 +65,7 @@ def main(argv=None):
                          "every depth)")
     ap.add_argument("--prefill-batch", action="store_true",
                     help="pack all prefilling slots into one (P, chunk) "
-                         "jitted step, P bucketed to {1,2,4,8}; requires "
-                         "--chunked-prefill")
+                         "jitted step, P bucketed to {1,2,4,8}")
     ap.add_argument("--prefix-cache", action="store_true",
                     help="share published prompt pages across requests "
                          "(refcounted, copy-on-write); the workload then "
@@ -200,9 +200,7 @@ def main(argv=None):
         model, params,
         ServerConfig(
             num_slots=args.num_slots, page_size=args.page_size,
-            max_seq_len=max_seq,
-            prefill_bucket=min(32, max(8, args.prompt_len)),
-            prefill_chunk=args.chunked_prefill or None,
+            max_seq_len=max_seq, prefill_chunk=args.chunked_prefill,
             prefix_cache=args.prefix_cache, preemption=args.preempt,
             async_depth=args.async_depth, prefill_batch=args.prefill_batch,
         ),
@@ -222,10 +220,6 @@ def main(argv=None):
         print("note: --async-depth is inert under speculative decoding — "
               "spec rounds are host-synchronous, the dispatch window "
               "collapses to 0")
-    if args.preempt and not args.chunked_prefill:
-        print("note: --preempt is inert without --chunked-prefill — "
-              "whole-prompt mode fully prefills a request in the step it "
-              "is admitted, so there is never a prefilling victim")
     server.warmup([len(p) for p in prompts])
 
     def submit(p, priority):
@@ -249,9 +243,8 @@ def main(argv=None):
         results = server.run()
     s = server.stats
     print(f"continuous: {len(results)} requests, {s.decode_tokens} decode "
-          f"tokens in {s.decode_steps} steps over {args.num_slots} slots"
-          + (f", prefill chunk {args.chunked_prefill}"
-             if args.chunked_prefill else ""))
+          f"tokens in {s.decode_steps} steps over {args.num_slots} slots, "
+          f"prefill chunk {args.chunked_prefill}")
     print(f"steady-state decode: {s.decode_tok_s:.1f} tok/s, "
           f"engine utilization {s.utilization:.0%}")
     ttft = server.ttft_percentiles()

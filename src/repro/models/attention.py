@@ -49,25 +49,22 @@ class PagedInfo(NamedTuple):
         fresh key/value; pad rows and inactive slots point into the null
         page (page 0), which is never read back as valid.
     read_idx: (B, L) flat pool indices covering each slot's page table in
-        position order, or None to attend over the fresh k/v only
-        (single-shot prefill). With Sq > 1 AND read_idx set (chunked
-        prefill), the layer attends over [gathered pool tokens | fresh k/v].
+        position order. With Sq == 1 (decode form) the layer attends over
+        these tokens, the fresh one included; with Sq > 1 (chunked form)
+        over [gathered pool tokens | fresh k/v].
     k_pos: key positions with POS_SENTINEL at invalid entries, matching the
-        attended keys: (B, Sq) when read_idx is None, (B, L) for decode,
-        (B, L + Sq) for chunked prefill.
+        attended keys: (B, L) in the decode form, (B, L + Sq) in the
+        chunked form.
     slots: (B,) state row per batch row — recurrent layers read/write their
         per-slot state pools through it (prefill gathers one row; decode
         covers all rows in order).
     starts: (B,) first absolute position of this chunk; start == 0 selects
         the fresh init state over the (stale, recycled) stored row.
     lengths: (B,) valid token count of each right-padded prefill row.
-    active: (B,) decode commit mask — inactive rows (free slots, slots mid
+    active: (B,) commit mask — inactive rows (free slots, slots mid
         chunked-prefill) keep their recurrent state untouched.
-    chunked: trace-time constant marking a chunked-prefill step (read_idx
-        set AND fresh k/v appended) — distinguishes it from decode, which
-        also sets read_idx but attends over the gathered keys only.
     pages: (B, pages_per_slot) physical page-table rows (NULL = 0), or None.
-        Required on a decode step under a Pallas backend, which runs the
+        Required in the decode form under a Pallas backend, which runs the
         fused paged flash-decode kernel; the XLA backend gathers through
         read_idx instead (the reference oracle the kernel is tested against).
     page_size: tokens per physical page (trace-time constant; only
@@ -75,13 +72,12 @@ class PagedInfo(NamedTuple):
     """
 
     write_idx: jnp.ndarray
-    read_idx: jnp.ndarray | None
+    read_idx: jnp.ndarray
     k_pos: jnp.ndarray
     slots: jnp.ndarray | None = None
     starts: jnp.ndarray | None = None
     lengths: jnp.ndarray | None = None
     active: jnp.ndarray | None = None
-    chunked: bool = False
     pages: jnp.ndarray | None = None
     page_size: int = 0
 
@@ -278,8 +274,8 @@ def apply(
             v.reshape(b * s, hkv, hd).astype(cache["vp"].dtype)
         )
         new_cache = {"kp": ck, "vp": cv}
-        if paged.read_idx is not None and paged.chunked:
-            # Chunked prefill: attend over [earlier chunks' tokens gathered
+        if s > 1:
+            # Chunked form: attend over [earlier chunks' tokens gathered
             # through the page table | this chunk's fresh k/v]. paged.k_pos
             # already covers the concatenation (gathered entries at
             # positions >= chunk start are sentinel-masked, so the fresh
@@ -290,34 +286,32 @@ def apply(
             v = jnp.concatenate(
                 [cv[paged.read_idx].astype(engine.policy.compute), v], axis=1
             )
-        elif paged.read_idx is not None:
-            if engine.backend in ("pallas", "pallas_interpret"):
-                if paged.pages is None or paged.active is None or s != 1:
-                    raise ValueError(
-                        "paged decode under a Pallas backend runs the "
-                        "page-walk kernel and needs PagedInfo.pages and "
-                        f".active with one query token (got pages="
-                        f"{paged.pages is not None}, active="
-                        f"{paged.active is not None}, tokens={s})"
-                    )
-                # Decode via the fused paged flash-decode kernel: the page
-                # table is scalar-prefetched into the kernel, which walks
-                # exactly the pages each slot owns (fp8 pools dequantize
-                # in-tile). No gather, no padded contiguous copy.
-                from repro.kernels import ops as kernel_ops
+        elif engine.backend in ("pallas", "pallas_interpret"):
+            if paged.pages is None or paged.active is None:
+                raise ValueError(
+                    "paged decode under a Pallas backend runs the page-walk "
+                    "kernel and needs PagedInfo.pages and .active (got "
+                    f"pages={paged.pages is not None}, "
+                    f"active={paged.active is not None})"
+                )
+            # Decode via the fused paged flash-decode kernel: the page
+            # table is scalar-prefetched into the kernel, which walks
+            # exactly the pages each slot owns (fp8 pools dequantize
+            # in-tile). No gather, no padded contiguous copy.
+            from repro.kernels import ops as kernel_ops
 
-                kernel_ctx = kernel_ops.paged_decode_attention(
-                    q[:, 0], ck, cv,
-                    paged.pages, paged.starts, paged.active,
-                    page_size=paged.page_size,
-                    window=cfg.window, softcap=cfg.softcap,
-                    backend=engine.backend,
-                )[:, None]  # (B, 1, Hq, hd)
-            else:
-                # XLA decode: gather every slot's pages in position order
-                # (the reference oracle of the kernel above).
-                k = ck[paged.read_idx].astype(engine.policy.compute)
-                v = cv[paged.read_idx].astype(engine.policy.compute)
+            kernel_ctx = kernel_ops.paged_decode_attention(
+                q[:, 0], ck, cv,
+                paged.pages, paged.starts, paged.active,
+                page_size=paged.page_size,
+                window=cfg.window, softcap=cfg.softcap,
+                backend=engine.backend,
+            )[:, None]  # (B, 1, Hq, hd)
+        else:
+            # XLA decode: gather every slot's pages in position order
+            # (the reference oracle of the kernel above).
+            k = ck[paged.read_idx].astype(engine.policy.compute)
+            v = cv[paged.read_idx].astype(engine.policy.compute)
         k_pos = paged.k_pos
     elif cache is not None and cross_kv is None:
         max_len = cache["k"].shape[1]

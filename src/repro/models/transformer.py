@@ -73,6 +73,54 @@ def _row_mask(mask, leaf):
     return mask.reshape(mask.shape + (1,) * (leaf.ndim - 1))
 
 
+def _slot_mappings(page_table, starts, lengths, active, slots, t: int, *,
+                   page_size: int) -> attention.PagedInfo:
+    """Slot mappings of one StateStore step over P rows of ``t`` tokens.
+
+    page_table: (P, Pps) page ids in position order; starts: (P,) position
+    of each row's first fresh token (= tokens already committed); lengths:
+    (P,) valid tokens per right-padded row, or None on a decode step;
+    active: (P,) rows taking part — the others write the null page;
+    slots: (P,) each row's state row.
+
+    It makes two shapes. ``t == 1`` is the decode form: the fresh token is
+    written, then the row attends over its pages up to and including it,
+    which is what the page-walk kernel reads; a width-1 row is valid iff
+    active. ``t > 1`` is the chunked form: the row attends over its
+    committed history gathered through the page table, followed by its
+    fresh keys (gathered entries at positions >= start are masked, so no
+    key counts twice).
+    """
+    rows, pps = page_table.shape
+    n_tok = pps * page_size
+    lpos = jnp.arange(n_tok, dtype=jnp.int32)[None]
+    if t == 1:
+        page = jnp.take_along_axis(
+            page_table, (starts // page_size)[:, None], axis=1)[:, 0]
+        write_idx = jnp.where(active, page * page_size + starts % page_size, 0)
+        k_pos = jnp.where(lpos <= starts[:, None], lpos, attention.POS_SENTINEL)
+    else:
+        tok = jnp.arange(t, dtype=jnp.int32)
+        pos = starts[:, None] + tok[None, :]
+        valid = (tok[None, :] < lengths[:, None]) & active[:, None]
+        page = jnp.take_along_axis(
+            page_table, jnp.clip(pos // page_size, 0, pps - 1), axis=1)
+        write_idx = jnp.where(
+            valid, page * page_size + pos % page_size, 0).reshape(rows * t)
+        read_pos = jnp.where(lpos < starts[:, None], lpos, attention.POS_SENTINEL)
+        fresh_pos = jnp.where(valid, pos, attention.POS_SENTINEL)
+        k_pos = jnp.concatenate([read_pos, fresh_pos], axis=1)
+    read_idx = (
+        page_table[:, :, None] * page_size
+        + jnp.arange(page_size, dtype=jnp.int32)[None, None, :]
+    ).reshape(rows, n_tok)
+    return attention.PagedInfo(
+        write_idx=write_idx, read_idx=read_idx, k_pos=k_pos, slots=slots,
+        starts=starts, lengths=lengths, active=active, pages=page_table,
+        page_size=page_size,
+    )
+
+
 @dataclasses.dataclass(frozen=True)
 class MeshCtx:
     """Distribution context handed to layers that need explicit collectives."""
@@ -691,124 +739,35 @@ class Transformer:
         }
         return {"units": units, "rem": rem}
 
-    def prefill_cb(self, params, tokens, pools, page_row, slot, start, length,
-                   *, page_size: int, chunked: bool = False, active=None,
+    def prefill_cb(self, params, tokens, pools, page_rows, slots, starts,
+                   lengths, active=None, *, page_size: int,
                    engine: Engine | None = None):
-        """One prefill chunk for one slot of the StateStore — or, in the
-        multi-row (batched) form, one chunk for each of P slots at once.
+        """One prefill chunk for each of P slots of the StateStore.
 
-        Single-row form — tokens: (1, Tb) right-padded chunk; page_row:
-        (P,) the slot's page ids; slot: () state row to read/commit;
-        start: () absolute position of the chunk's first token (start == 0
-        resets recurrent state rows — that is how a recycled slot forgets
-        its previous request); length: () valid tokens in this chunk. With
-        ``chunked`` (a trace-time constant), attention also gathers the
-        earlier chunks' K/V back through the page table; recurrent layers
-        continue from the stored state row either way. Pad rows compute
-        garbage that never escapes: their keys are masked (POS_SENTINEL),
-        their K/V writes land in the null page, and masked scans skip their
-        state updates. Returns (logits (1, V) at the chunk's last valid
-        position, new pools).
-
-        Multi-row form (selected by a rank-2 ``page_row``) — tokens:
-        (P, Tb); page_row: (P, Pps); slot/start/length: (P,) vectors;
-        ``active``: (P,) bool marking the real rows. Structurally this is
-        ``verify_cb`` with per-row starts: each row gathers ITS committed
-        K/V back through ITS page row, appends its fresh chunk, and commits
-        its own state row. Per-row math is identical to the single-row
-        chunked step (rows never mix), so a batched prefill is bitwise
-        equal to P serial chunked prefills under greedy sampling. Inactive
-        pad rows write the null page and must carry slot ids distinct from
-        every active row in the call — their masked state write-back
-        scatters the OLD row value, which would race a real update on a
-        shared index. Requires ``chunked=True``. Returns (logits (P, V) at
-        each row's last valid position, new pools)."""
+        tokens: (P, Tb) right-padded chunks; page_rows: (P, Pps) each
+        slot's page ids; slots/starts/lengths: (P,) state row, absolute
+        position of the chunk's first token, valid tokens in the chunk;
+        active: (P,) bool marking the real rows (default: all). Each row
+        attends over ITS committed K/V, gathered back through ITS page row,
+        followed by its fresh chunk, and commits its own state row (start
+        == 0 resets it — that is how a recycled slot forgets its previous
+        request). Rows never mix, so P rows give the logits and pools of P
+        one-row calls. Pad tokens compute garbage that never escapes:
+        their keys are masked (POS_SENTINEL), their K/V writes land in the
+        null page, and masked scans skip their state updates. Inactive rows
+        must carry slot ids distinct from every active row in the call —
+        their masked state write-back scatters the OLD row value, which
+        would race a real update on a shared index. Returns (logits (P, V)
+        at each row's last valid position, new pools)."""
         eng = as_engine(engine) if engine is not None else self.engine
-        if jnp.ndim(page_row) == 2:
-            if not chunked:
-                raise ValueError(
-                    "multi-row prefill_cb is always chunked (each row "
-                    "gathers its own committed K/V back through its page "
-                    "row); call with chunked=True"
-                )
-            return self._prefill_cb_batched(
-                params, tokens, pools, page_row, slot, start, length,
-                active, page_size=page_size, engine=eng,
-            )
-        b, s = tokens.shape
-        tok = jnp.arange(s, dtype=jnp.int32)
-        pos = start + tok
-        valid = tok < length
-        page_idx = jnp.clip(pos // page_size, 0, page_row.shape[0] - 1)
-        write_idx = jnp.where(
-            valid, page_row[page_idx] * page_size + pos % page_size, 0
-        )
-        fresh_pos = jnp.where(valid, pos, attention.POS_SENTINEL)[None]
-        if chunked:
-            n_tok = page_row.shape[0] * page_size
-            read_idx = (
-                page_row[:, None] * page_size
-                + jnp.arange(page_size, dtype=jnp.int32)[None, :]
-            ).reshape(1, n_tok)
-            lpos = jnp.arange(n_tok, dtype=jnp.int32)[None]
-            read_pos = jnp.where(lpos < start, lpos, attention.POS_SENTINEL)
-            k_pos = jnp.concatenate([read_pos, fresh_pos], axis=1)
-        else:
-            read_idx = None
-            k_pos = fresh_pos
-        paged = attention.PagedInfo(
-            write_idx=write_idx, read_idx=read_idx, k_pos=k_pos,
-            slots=jnp.atleast_1d(slot), starts=jnp.atleast_1d(start),
-            lengths=jnp.atleast_1d(length), active=jnp.ones((b,), bool),
-            chunked=chunked,
-        )
-        x = self.embed(params, tokens, engine=eng)
-        positions = jnp.broadcast_to(pos[None], (b, s))
-        x, new_pools, _ = self._run_stack(
-            params["decoder"], x, positions, eng, cache=pools, paged=paged
-        )
-        with jax.named_scope("lm_head"):
-            x = common.norm_apply(params["final_norm"], x, self.cfg.norm)
-            x_last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
-            logits = self.logits(params, x_last, engine=eng)
-        return logits[:, 0], new_pools
-
-    def _prefill_cb_batched(self, params, tokens, pools, page_rows, slots,
-                            starts, lengths, active, *, page_size: int,
-                            engine: Engine):
-        """Multi-row body of :meth:`prefill_cb` (see its docstring)."""
-        eng = engine
         b, s = tokens.shape
         act = jnp.ones((b,), bool) if active is None else jnp.asarray(active)
-        slots = jnp.asarray(slots)
-        starts = jnp.asarray(starts)
         lengths = jnp.asarray(lengths)
-        tok = jnp.arange(s, dtype=jnp.int32)
-        pos = starts[:, None] + tok[None, :]  # (P, Tb) absolute positions
-        valid = (tok[None, :] < lengths[:, None]) & act[:, None]
-        page_idx = jnp.clip(pos // page_size, 0, page_rows.shape[1] - 1)
-        page = jnp.take_along_axis(page_rows, page_idx, axis=1)
-        write_idx = jnp.where(
-            valid, page * page_size + pos % page_size, 0
-        ).reshape(b * s)
-        fresh_pos = jnp.where(valid, pos, attention.POS_SENTINEL)
-        n_tok = page_rows.shape[1] * page_size
-        read_idx = (
-            page_rows[:, :, None] * page_size
-            + jnp.arange(page_size, dtype=jnp.int32)[None, None, :]
-        ).reshape(b, n_tok)
-        lpos = jnp.arange(n_tok, dtype=jnp.int32)[None]
-        read_pos = jnp.where(lpos < starts[:, None], lpos, attention.POS_SENTINEL)
-        k_pos = jnp.concatenate([read_pos, fresh_pos], axis=1)
-        paged = attention.PagedInfo(
-            write_idx=write_idx, read_idx=read_idx, k_pos=k_pos,
-            slots=slots, starts=starts, lengths=lengths, active=act,
-            chunked=True,
+        paged = _slot_mappings(
+            page_rows, jnp.asarray(starts), lengths, act, jnp.asarray(slots),
+            s, page_size=page_size,
         )
-        x = self.embed(params, tokens, engine=eng)
-        x, new_pools, _ = self._run_stack(
-            params["decoder"], x, pos, eng, cache=pools, paged=paged
-        )
+        x, new_pools = self._run_chunk(params, tokens, pools, paged, eng)
         with jax.named_scope("lm_head"):
             x = common.norm_apply(params["final_norm"], x, self.cfg.norm)
             last = jnp.clip(lengths - 1, 0, s - 1)[:, None, None]
@@ -829,28 +788,15 @@ class Transformer:
         regardless of which slots are live. Returns (logits (S, V), new
         pools)."""
         eng = as_engine(engine) if engine is not None else self.engine
-        n_slots = tokens.shape[0]
-        positions = seq_lens[:, None]  # (S, 1): per-slot decode position
-        cur_page = jnp.take_along_axis(
-            page_table, (seq_lens // page_size)[:, None], axis=1
-        )[:, 0]
-        write_idx = jnp.where(active, cur_page * page_size + seq_lens % page_size, 0)
-        n_tok = page_table.shape[1] * page_size
-        read_idx = (
-            page_table[:, :, None] * page_size
-            + jnp.arange(page_size, dtype=jnp.int32)[None, None, :]
-        ).reshape(n_slots, n_tok)
-        lpos = jnp.arange(n_tok, dtype=jnp.int32)[None]
-        k_pos = jnp.where(lpos <= seq_lens[:, None], lpos, attention.POS_SENTINEL)
-        paged = attention.PagedInfo(
-            write_idx=write_idx, read_idx=read_idx, k_pos=k_pos,
-            slots=jnp.arange(n_slots, dtype=jnp.int32), starts=seq_lens,
-            active=active, pages=page_table, page_size=page_size,
+        paged = _slot_mappings(
+            page_table, seq_lens, None, active,
+            jnp.arange(tokens.shape[0], dtype=jnp.int32), 1,
+            page_size=page_size,
         )
         x = self.embed(params, tokens, engine=eng)
         x, new_pools, _ = self._run_stack(
-            params["decoder"], x, positions, eng, cache=pools, decode=True,
-            paged=paged,
+            params["decoder"], x, seq_lens[:, None], eng, cache=pools,
+            decode=True, paged=paged,
         )
         with jax.named_scope("lm_head"):
             x = common.norm_apply(params["final_norm"], x, self.cfg.norm)
@@ -866,11 +812,10 @@ class Transformer:
         right-padded; page_table: (S, P); seq_lens: (S,) tokens already
         committed per slot (= the first fresh position); lengths: (S,) valid
         tokens per row (0 for rows sitting this round out); active: (S,)
-        rows taking part. Structurally this is ``prefill_cb``'s chunked path
-        lifted to all slots at once — gather the committed K/V back through
-        the page table, append the fresh row, attend causally — except
-        logits come back for EVERY position (S, T, V): logits[:, i] is the
-        target distribution after token i, which is what judges draft i+1.
+        rows taking part. The slot mappings are ``prefill_cb``'s, over all
+        slots at once, but logits come back for EVERY position (S, T, V):
+        logits[:, i] is the target distribution after token i, which is
+        what judges draft i+1.
 
         ``commit`` (trace-time) gates recurrent state-row commits. The
         verify pass runs with commit=False: the accepted prefix is not known
@@ -884,38 +829,28 @@ class Transformer:
         """
         eng = as_engine(engine) if engine is not None else self.engine
         n_slots, t = tokens.shape
-        tok = jnp.arange(t, dtype=jnp.int32)
-        pos = seq_lens[:, None] + tok[None, :]  # (S, T)
-        valid = (tok[None, :] < lengths[:, None]) & active[:, None]
-        page_idx = jnp.clip(pos // page_size, 0, page_table.shape[1] - 1)
-        page = jnp.take_along_axis(page_table, page_idx, axis=1)
-        write_idx = jnp.where(
-            valid, page * page_size + pos % page_size, 0
-        ).reshape(n_slots * t)
-        fresh_pos = jnp.where(valid, pos, attention.POS_SENTINEL)
-        n_tok = page_table.shape[1] * page_size
-        read_idx = (
-            page_table[:, :, None] * page_size
-            + jnp.arange(page_size, dtype=jnp.int32)[None, None, :]
-        ).reshape(n_slots, n_tok)
-        lpos = jnp.arange(n_tok, dtype=jnp.int32)[None]
-        read_pos = jnp.where(lpos < seq_lens[:, None], lpos, attention.POS_SENTINEL)
-        k_pos = jnp.concatenate([read_pos, fresh_pos], axis=1)
-        paged = attention.PagedInfo(
-            write_idx=write_idx, read_idx=read_idx, k_pos=k_pos,
-            slots=jnp.arange(n_slots, dtype=jnp.int32), starts=seq_lens,
-            lengths=lengths,
-            active=active if commit else jnp.zeros_like(active),
-            chunked=True,
+        paged = _slot_mappings(
+            page_table, seq_lens, lengths, active,
+            jnp.arange(n_slots, dtype=jnp.int32), t, page_size=page_size,
         )
-        x = self.embed(params, tokens, engine=eng)
-        x, new_pools, _ = self._run_stack(
-            params["decoder"], x, pos, eng, cache=pools, paged=paged
-        )
+        if not commit:
+            paged = paged._replace(active=jnp.zeros_like(active))
+        x, new_pools = self._run_chunk(params, tokens, pools, paged, eng)
         with jax.named_scope("lm_head"):
             x = common.norm_apply(params["final_norm"], x, self.cfg.norm)
             logits = self.logits(params, x, engine=eng)
         return logits, new_pools
+
+    def _run_chunk(self, params, tokens, pools, paged, eng):
+        """Embed (P, T) fresh tokens at their rows' positions and run the
+        stack over the StateStore. Returns (hidden (P, T, D), new pools)."""
+        positions = paged.starts[:, None] + jnp.arange(
+            tokens.shape[1], dtype=jnp.int32)[None, :]
+        x = self.embed(params, tokens, engine=eng)
+        x, new_pools, _ = self._run_stack(
+            params["decoder"], x, positions, eng, cache=pools, paged=paged
+        )
+        return x, new_pools
 
     def prefill(self, params, batch, cache, *, engine: Engine | None = None):
         """Run the prompt through the decoder, filling caches."""

@@ -43,9 +43,8 @@ Track layout (see docs/DESIGN.md, Observability):
   the host's step loop, written through ``span`` and so also in a profiler
   trace: ``server.step`` (one per ``Server.step()``) holding
   ``server.admit`` (admission, preemption, prefix mapping, gauges), one
-  span per jitted-step *dispatch* (``prefill_full.dispatch`` /
-  ``prefill_chunk.dispatch`` / ``prefill_batch.dispatch`` /
-  ``decode.dispatch``, or the synchronous ``spec_round`` with nested
+  span per jitted-step *dispatch* (``prefill_chunk.dispatch`` /
+  ``prefill_batch.dispatch`` / ``decode.dispatch``, or the synchronous ``spec_round`` with nested
   ``draft`` / ``verify`` / ``commit`` phases), ``harvest.wait`` (the block
   on the oldest step and the copy of its sampled tokens to the host) and
   ``server.commit`` (token events, finishes and page recycling of that

@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.engine import resolve_engine
 from repro.obs import (
     DEVICE_INFLIGHT_TID,
     DEVICE_TID,
@@ -63,9 +64,8 @@ from repro.obs import (
     weight_prep_tally,
 )
 from repro.serving.cache import StateStore, copy_kv_page
-from repro.serving.sampling import GREEDY, sample_logits, stack_params
-from repro.training import make_paged_serve_steps
-from repro.training.loop import resolve_engine
+from repro.serving.sampling import sample_logits, stack_params
+from repro.serving.steps import make_paged_serve_steps
 
 # Allowed P values for batched multi-slot prefill. Bucketing the row count
 # (instead of compiling one shape per prefilling-set size) bounds the
@@ -77,7 +77,7 @@ P_BUCKETS = (1, 2, 4, 8)
 class InflightStep:
     """One dispatched-but-not-yet-harvested device step."""
 
-    kind: str  # prefill_full | prefill_chunk | prefill_batch | decode
+    kind: str  # prefill_chunk | prefill_batch | decode
     bucket: int  # profiler shape bucket (chunk size, P*chunk, or num_slots)
     t_dispatch: float  # perf_counter just before the jit call
     done: Any  # device array whose readiness marks step completion
@@ -124,14 +124,10 @@ class EngineCore:
         self.tracer = tracer if tracer is not None else NullTracer()
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.profiler = profiler if profiler is not None else StepProfiler()
-        prefill_full, prefill_chunk, prefill_batch, decode_step = (
-            make_paged_serve_steps(
-                model, page_size=config.page_size, engine=engine,
-                backend=backend,
-            )
+        prefill_chunk, prefill_batch, decode_step = make_paged_serve_steps(
+            model, page_size=config.page_size, engine=engine, backend=backend,
         )
         self.weight_prep: dict[str, WeightPrep] = {}
-        self._prefill_full = self._jit("prefill_full", prefill_full)
         self._prefill_chunk = self._jit("prefill_chunk", prefill_chunk)
         self._prefill_batch = self._jit("prefill_batch", prefill_batch)
         self._decode = self._jit("decode", decode_step)
@@ -211,12 +207,9 @@ class EngineCore:
         cfg, prof = self.config, self.profile
         if not prof.needs_kv_pages:
             return 0
-        if prof.kv_window is not None and cfg.prefill_chunk is not None:
+        if prof.kv_window is not None:
             # Window + one in-flight chunk + slack pages so lazy allocation
-            # ahead of recycling never outruns the reservation. Only sound
-            # under chunked prefill: whole-prompt prefill allocates every
-            # prompt page at once (recycling runs after the jitted call),
-            # so its peak demand is the full prompt, not a window.
+            # ahead of recycling never outruns the reservation.
             return min(cfg.max_seq_len,
                        prof.kv_window + cfg.prefill_chunk + 2 * cfg.page_size)
         return None
@@ -266,22 +259,20 @@ class EngineCore:
         self._inflight.append(step)
         self._g_inflight.set(len(self._inflight))
 
-    def dispatch_prefill(self, *, kind: str, tokens: np.ndarray,
-                         page_row: np.ndarray, slot: int, start: int, n: int,
-                         bucket: int, sampling=None, payload=None,
-                         rid: int = -1) -> None:
-        """Enqueue one single-row prefill step (``prefill_full`` or
-        ``prefill_chunk``). ``sampling`` non-None marks the final chunk:
-        the first token is sampled on-device and scattered into the
-        last-token array so decode can be dispatched against it."""
+    def dispatch_prefill(self, *, tokens: np.ndarray, page_row: np.ndarray,
+                         slot: int, start: int, n: int, sampling=None,
+                         payload=None, rid: int = -1) -> None:
+        """Enqueue one single-row (1, chunk) prefill step. ``sampling``
+        non-None marks the final chunk: the first token is sampled
+        on-device and scattered into the last-token array so decode can be
+        dispatched against it."""
+        bucket = tokens.shape[1]
         targs = {"rid": rid, "slot": slot, "start": start, "tokens": n,
                  "bucket": bucket}
         t0 = time.perf_counter()
-        with self.tracer.span(PID_DEVICE, DEVICE_TID, f"{kind}.dispatch",
+        with self.tracer.span(PID_DEVICE, DEVICE_TID, "prefill_chunk.dispatch",
                               **targs):
-            fn = (self._prefill_full if kind == "prefill_full"
-                  else self._prefill_chunk)
-            logits, pools = fn(
+            logits, pools = self._prefill_chunk(
                 self.params, jnp.asarray(tokens), self.cache.pools,
                 jnp.asarray(page_row), jnp.int32(slot), jnp.int32(start),
                 jnp.int32(n),
@@ -295,7 +286,7 @@ class EngineCore:
                     self._last_tok, jnp.int32(slot), toks[0]
                 )
         self._record(InflightStep(
-            kind=kind, bucket=bucket, t_dispatch=t0, done=logits, toks=toks,
+            kind="prefill_chunk", bucket=bucket, t_dispatch=t0, done=logits, toks=toks,
             payload=payload, trace_args=targs,
         ))
 

@@ -56,7 +56,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.training import make_serve_steps
 from repro.obs import (
     DEVICE_TID,
     PID_DEVICE,
@@ -80,6 +79,7 @@ from repro.serving.spec import (
     Verifier,
     effective_k,
 )
+from repro.serving.steps import make_serve_steps
 
 log = logging.getLogger(__name__)
 
@@ -97,10 +97,9 @@ class ServerConfig:
     # so admission is gated by slots, not pages.
     num_pages: Optional[int] = None
     token_budget: Optional[int] = None  # cap on sum(max_total) in flight
-    prefill_bucket: int = 32  # unchunked prompts pad up to a multiple of this
-    # Chunked prefill: prompts advance one fixed-size chunk per step,
-    # interleaved with decode steps. None = whole-prompt prefill.
-    prefill_chunk: Optional[int] = None
+    # Prompts advance one fixed-size chunk per step, interleaved with
+    # decode steps: one compiled prefill shape whatever the prompt length.
+    prefill_chunk: int = 256
     # Prefix caching: published full prompt pages are shared (refcounted,
     # copy-on-write on a partial tail) into later requests with the same
     # prompt prefix. Auto-disabled for models with recurrent state rows —
@@ -120,7 +119,7 @@ class ServerConfig:
     async_depth: int = 0
     # Batched multi-slot prefill: pack every prefilling slot into one
     # (P, prefill_chunk) jitted step, P bucketed to {1, 2, 4, 8} (clamped
-    # to num_slots). Requires prefill_chunk.
+    # to num_slots).
     prefill_batch: bool = False
 
     @property
@@ -129,12 +128,6 @@ class ServerConfig:
         # table always spans max_seq_len even when reservation is windowed
         # (recycled entries go back to NULL_PAGE).
         return -(-self.max_seq_len // self.page_size)
-
-    def bucket(self, prompt_len: int) -> int:
-        if self.prefill_chunk is not None:
-            return self.prefill_chunk
-        b = self.prefill_bucket
-        return -(-prompt_len // b) * b
 
 
 class TokenEvent(NamedTuple):
@@ -307,11 +300,6 @@ class Server:
             config = ServerConfig()
         if config.async_depth < 0:
             raise ValueError("async_depth must be >= 0")
-        if config.prefill_batch and config.prefill_chunk is None:
-            raise ValueError(
-                "prefill_batch packs (P, prefill_chunk) steps and needs a "
-                "fixed chunk shape: set prefill_chunk"
-            )
         # Observability: tracer defaults to the zero-overhead NullTracer
         # (hot paths gate on tracer.enabled before building event args);
         # the metrics registry is always on — it IS the stats store.
@@ -427,13 +415,6 @@ class Server:
         self._h_acc_round = m.histogram(
             "serving_spec_accepted_per_round", bounds=list(range(33)),
             help="accepted drafts per decoding row per verify round")
-
-    # -- pool sizing (delegated to the engine) -----------------------------
-    def _reserve_tokens_cap(self) -> Optional[int]:
-        return self.engine.reserve_tokens_cap()
-
-    def _resolved_num_pages(self) -> int:
-        return self.engine.resolved_num_pages()
 
     @property
     def cache(self):
@@ -583,17 +564,11 @@ class Server:
         return tuple(float(np.percentile(ttft, q)) for q in qs)
 
     def warmup(self, prompt_lens: Iterable[int], max_new_tokens: int = 2) -> None:
-        """Compile the decode/sampling steps and every prefill bucket the
-        given prompt lengths hit (one fixed chunk shape when chunked
-        prefill is on), then reset serving state — so a timed run right
-        after measures steady state only."""
-        seen: set[int] = set()
-        for pl in prompt_lens:
-            tb = self.config.bucket(pl)
-            if tb in seen:
-                continue
-            seen.add(tb)
-            self.submit([1] * pl, max_new_tokens=max_new_tokens)
+        """Compile the chunk, sampling and decode steps — one shape each,
+        whatever the prompt length, so one prompt of the shortest given
+        length does — then reset serving state, so a timed run right after
+        measures steady state only."""
+        self.submit([1] * min(prompt_lens), max_new_tokens=max_new_tokens)
         self.run()
         self.reset()
 
@@ -680,34 +655,24 @@ class Server:
 
     # -- dispatch (optimistic host state) ----------------------------------
     def _dispatch_prefill_serial(self, req: Request) -> None:
-        """Dispatch one prompt chunk for one slot. A prefix-hit request
-        starts at the first uncached position — its chunk must gather the
-        mapped pages' K/V back through the page table, so it always takes
-        the chunked step even when chunked prefill is off (the suffix then
-        runs as one bucketed chunk)."""
-        cfg = self.config
+        """Dispatch one prompt chunk for one slot, from its first position
+        not yet prefilled (past a prefix-cache hit, the chunk reads the
+        mapped pages' K/V back through the page table)."""
+        chunk = self.config.prefill_chunk
         ds = self._disp[req.rid]
         start = ds.prefilled
-        if cfg.prefill_chunk is None:
-            n = req.prompt_len - start
-            tb = cfg.bucket(n)
-            kind = "prefill_chunk" if start > 0 else "prefill_full"
-        else:
-            n = min(cfg.prefill_chunk, req.prompt_len - start)
-            tb = cfg.prefill_chunk
-            kind = "prefill_chunk"
+        n = min(chunk, req.prompt_len - start)
         if self.profile.needs_kv_pages:
             self._mirror_pages(req, self.scheduler.ensure_pages(req, start + n))
-        toks = np.zeros((1, tb), np.int32)
+        toks = np.zeros((1, chunk), np.int32)
         toks[0, :n] = req.prompt[start:start + n]
         final = start + n == req.prompt_len
         # The StateStore mirror is the single source of truth for the row
         # (kept in sync by _mirror_pages / clear_pages / reset_slot);
         # copied so later host mutations can't leak into the snapshot.
         self.engine.dispatch_prefill(
-            kind=kind, tokens=toks,
-            page_row=self.cache.page_table[req.slot].copy(),
-            slot=req.slot, start=start, n=n, bucket=tb,
+            tokens=toks, page_row=self.cache.page_table[req.slot].copy(),
+            slot=req.slot, start=start, n=n,
             sampling=req.sampling if final else None,
             payload=[(req, ds.epoch, start, n, final)], rid=req.rid,
         )
@@ -733,10 +698,9 @@ class Server:
         for i in range(0, len(prefilling), max_b):
             group = prefilling[i:i + max_b]
             if len(group) == 1:
-                # A single prefilling request takes the serial (1, chunk)
-                # path: the batched step's row scatter/masking machinery
-                # costs ~30% on one row for nothing (greedy outputs are
-                # identical either way).
+                # A single prefilling request takes the (1, chunk) step:
+                # the same model body as a one-row batch, under the program
+                # the serial path compiles anyway.
                 self._dispatch_prefill_serial(group[0])
                 dispatched += 1
                 continue

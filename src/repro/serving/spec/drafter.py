@@ -33,7 +33,7 @@ import numpy as np
 from repro.serving.cache import StateStore
 from repro.serving.engine import serving_params
 from repro.serving.sampling import sample_logits, stack_params
-from repro.training import make_paged_serve_steps, make_spec_verify_steps
+from repro.serving.steps import make_paged_serve_steps, make_spec_verify_steps
 
 
 def _draft_histogram(metrics):
@@ -141,7 +141,7 @@ class ModelDrafter:
         _, commit_step = make_spec_verify_steps(
             model, page_size=page_size, engine=engine, backend=backend,
         )
-        _, _, _, decode_step = make_paged_serve_steps(
+        *_, decode_step = make_paged_serve_steps(
             model, page_size=page_size, engine=engine, backend=backend,
         )
         self._catch_up = jax.jit(commit_step)

@@ -6,7 +6,7 @@ rejection sampler, so the server's speculative loop stays a thin host
 orchestration:
 
   1. ``verify`` runs all slots' [last token | drafts] rows through the
-     target in ONE batched chunked-prefill-style step, returning logits at
+     target in ONE batched prefill-chunk-style step, returning logits at
      every position. Recurrent state rows do NOT commit here — the
      accepted prefix is unknown until the sampler runs. K/V for every
      fielded position is written through the page table; positions past
@@ -26,7 +26,7 @@ from typing import Optional
 import jax
 
 from repro.serving.spec.rejection import speculative_sample
-from repro.training import make_spec_verify_steps
+from repro.serving.steps import make_spec_verify_steps
 
 
 class Verifier:

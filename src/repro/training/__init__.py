@@ -2,9 +2,6 @@ from repro.training.loop import (
     TrainState,
     chunked_xent,
     make_loss_fn,
-    make_paged_serve_steps,
-    make_serve_steps,
-    make_spec_verify_steps,
     make_train_step,
 )
 
@@ -12,8 +9,5 @@ __all__ = [
     "TrainState",
     "chunked_xent",
     "make_loss_fn",
-    "make_paged_serve_steps",
-    "make_serve_steps",
-    "make_spec_verify_steps",
     "make_train_step",
 ]

@@ -1,4 +1,4 @@
-"""Training/serving step factories: loss, metrics, anomaly guard.
+"""Training step factories: loss, metrics, anomaly guard.
 
 The cross-entropy is computed in sequence chunks with per-chunk
 rematerialization so the (B, S, vocab) logits tensor is never materialized —
@@ -11,25 +11,11 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.engine import Engine, as_engine, current_engine, engine_scope
+from repro.engine import Engine, engine_scope, resolve_engine
 from repro.models.transformer import Transformer
 
 AUX_LOSS_WEIGHT = 0.01
 XENT_CHUNK = 512
-
-
-def resolve_engine(model, engine: Engine | None = None,
-                   backend: str | None = None) -> Engine:
-    """Engine resolution for the step factories: explicit engine > model's
-    configured engine > ambient scope; ``backend`` then overrides the
-    execution backend (the launcher CLI knob)."""
-    if engine is not None:
-        eng = as_engine(engine)
-    else:
-        eng = getattr(model, "engine", None) or current_engine()
-    if backend:
-        eng = eng.with_backend(backend)
-    return eng
 
 
 def _shift_labels(tokens):
@@ -157,120 +143,3 @@ def make_train_step(model: Transformer, optimizer, *, anomaly_guard: bool = True
         return new_state, metrics
 
     return train_step
-
-
-def make_serve_steps(model: Transformer, *, engine: Engine | None = None,
-                     backend: str | None = None):
-    """(prefill_step, decode_step) pair for static-batch serving: every
-    sequence in the batch shares one position and one ring-buffer cache."""
-    eng = resolve_engine(model, engine, backend)
-
-    def prefill_step(params, batch, max_len: int):
-        cross = batch["frames"].shape[1] if "frames" in batch else 0
-        cache = model.init_cache(batch["tokens"].shape[0], max_len, cross_len=cross)
-        with engine_scope(eng):
-            logits, cache = model.prefill(params, batch, cache, engine=eng)
-        return logits, cache
-
-    def decode_step(params, tokens, cache):
-        with engine_scope(eng):
-            return model.decode_step(params, tokens, cache, engine=eng)
-
-    return prefill_step, decode_step
-
-
-def make_paged_serve_steps(model: Transformer, *, page_size: int,
-                           engine: Engine | None = None,
-                           backend: str | None = None):
-    """Slot-aware (prefill_full, prefill_chunk, prefill_batch, decode_step)
-    quadruple over the serving StateStore — the fixed-shape steps the
-    continuous-batching scheduler drives (``repro.serving``) for EVERY
-    decoder-only family: attention layers page K/V, recurrent layers
-    read/commit per-slot state rows. ``prefill_full`` runs a whole
-    right-padded prompt in one call (attends over the fresh k/v only);
-    ``prefill_chunk`` runs one chunk of a longer prompt, additionally
-    gathering earlier chunks' K/V back through the page table;
-    ``prefill_batch`` runs one chunk for each of P slots in a single step
-    (the multi-slot path — per-row math identical to P serial chunked
-    calls, inactive pad rows masked to the null page). Each decode covers
-    every slot at its own length, committing only ``active`` rows.
-
-    prefill_full/chunk(params, tokens (1, Tb), pools, page_row (P,),
-              slot (), start (), length ()) -> (logits (1, V), pools)
-    prefill_batch(params, tokens (P, Tb), pools, page_rows (P, Pps),
-              slots (P,), starts (P,), lengths (P,), active (P,))
-              -> (logits (P, V), pools)
-    decode_step(params, tokens (S, 1), pools, page_table (S, P),
-                seq_lens (S,), active (S,)) -> (logits (S, V), pools)
-    """
-    eng = resolve_engine(model, engine, backend)
-
-    def prefill_full(params, tokens, pools, page_row, slot, start, length):
-        with engine_scope(eng):
-            return model.prefill_cb(
-                params, tokens, pools, page_row, slot, start, length,
-                page_size=page_size, chunked=False, engine=eng,
-            )
-
-    def prefill_chunk(params, tokens, pools, page_row, slot, start, length):
-        with engine_scope(eng):
-            return model.prefill_cb(
-                params, tokens, pools, page_row, slot, start, length,
-                page_size=page_size, chunked=True, engine=eng,
-            )
-
-    def prefill_batch(params, tokens, pools, page_rows, slots, starts,
-                      lengths, active):
-        with engine_scope(eng):
-            return model.prefill_cb(
-                params, tokens, pools, page_rows, slots, starts, lengths,
-                page_size=page_size, chunked=True, active=active, engine=eng,
-            )
-
-    def decode_step(params, tokens, pools, page_table, seq_lens, active):
-        with engine_scope(eng):
-            return model.decode_cb(
-                params, tokens, pools, page_table, seq_lens, active,
-                page_size=page_size, engine=eng,
-            )
-
-    return prefill_full, prefill_chunk, prefill_batch, decode_step
-
-
-def make_spec_verify_steps(model: Transformer, *, page_size: int,
-                           engine: Engine | None = None,
-                           backend: str | None = None):
-    """(verify_step, commit_step) pair for speculative decoding over the
-    StateStore (``repro.serving.spec``). Both run the same slot-batched
-    multi-token step (``Transformer.verify_cb`` — chunked prefill lifted to
-    all slots, logits at every position) and differ only in whether
-    recurrent state rows commit:
-
-    ``verify_step`` leaves state rows untouched (the accepted prefix isn't
-    known until rejection sampling runs); ``commit_step`` re-scans with
-    ``lengths`` clamped to the accepted counts, advancing state rows exactly
-    through the accepted tokens. Attention-only targets skip the commit
-    pass — K/V written past the accepted boundary is never read back.
-    ``commit_step`` also doubles as the drafter's batched catch-up prefill.
-
-    verify/commit(params, tokens (S, T), pools, page_table (S, P),
-                  seq_lens (S,), lengths (S,), active (S,))
-        -> (logits (S, T, V), pools)
-    """
-    eng = resolve_engine(model, engine, backend)
-
-    def verify_step(params, tokens, pools, page_table, seq_lens, lengths, active):
-        with engine_scope(eng):
-            return model.verify_cb(
-                params, tokens, pools, page_table, seq_lens, lengths, active,
-                page_size=page_size, commit=False, engine=eng,
-            )
-
-    def commit_step(params, tokens, pools, page_table, seq_lens, lengths, active):
-        with engine_scope(eng):
-            return model.verify_cb(
-                params, tokens, pools, page_table, seq_lens, lengths, active,
-                page_size=page_size, commit=True, engine=eng,
-            )
-
-    return verify_step, commit_step

@@ -54,7 +54,7 @@ _GENS = (6, 3, 8, 5)
 
 
 def _run(model, params, prompts, gens, tracer=None, **cfg_kw):
-    kw = dict(num_slots=2, page_size=4, max_seq_len=24, prefill_bucket=8)
+    kw = dict(num_slots=2, page_size=4, max_seq_len=24, prefill_chunk=8)
     kw.update(cfg_kw)
     server = Server(model, params, ServerConfig(**kw), tracer=tracer)
     reqs = [server.submit(p, max_new_tokens=g)
@@ -73,9 +73,6 @@ def test_config_validation(served_model):
     with pytest.raises(ValueError, match="async_depth"):
         Server(model, params, ServerConfig(
             num_slots=2, page_size=4, max_seq_len=24, async_depth=-1))
-    with pytest.raises(ValueError, match="prefill_chunk"):
-        Server(model, params, ServerConfig(
-            num_slots=2, page_size=4, max_seq_len=24, prefill_batch=True))
 
 
 # -- P-bucketing policy -------------------------------------------------------
@@ -85,7 +82,7 @@ def test_bucket_policy(served_model):
     bucket_for picks the smallest bucket covering the group."""
     _, model, params = served_model
     server = Server(model, params, ServerConfig(
-        num_slots=6, page_size=4, max_seq_len=24, prefill_bucket=8,
+        num_slots=6, page_size=4, max_seq_len=24,
         prefill_chunk=4, prefill_batch=True))
     eng = server.engine
     assert P_BUCKETS == (1, 2, 4, 8)
@@ -93,7 +90,7 @@ def test_bucket_policy(served_model):
     assert [eng.bucket_for(n) for n in (1, 2, 3, 4)] == [1, 2, 4, 4]
 
     server1 = Server(model, params, ServerConfig(
-        num_slots=1, page_size=4, max_seq_len=24, prefill_bucket=8,
+        num_slots=1, page_size=4, max_seq_len=24,
         prefill_chunk=4, prefill_batch=True))
     assert server1.engine.allowed_buckets() == (1,)
 
@@ -157,13 +154,13 @@ def test_eos_overshoot_discarded(served_model):
     cfg, model, params = served_model
     (prompt,) = _prompts(cfg, (6,), seed=5)
     server = Server(model, params, ServerConfig(
-        num_slots=1, page_size=4, max_seq_len=16, prefill_bucket=8))
+        num_slots=1, page_size=4, max_seq_len=16, prefill_chunk=8))
     req = server.submit(prompt, max_new_tokens=5)
     first = server.run()[req.rid].out_tokens
     eos = first[1]
     for depth in (1, 3):
         server = Server(model, params, ServerConfig(
-            num_slots=1, page_size=4, max_seq_len=16, prefill_bucket=8,
+            num_slots=1, page_size=4, max_seq_len=16, prefill_chunk=8,
             async_depth=depth))
         req = server.submit(prompt, max_new_tokens=5, eos_id=eos)
         out = server.run()[req.rid].out_tokens
@@ -183,7 +180,7 @@ def test_latency_marks_stamped_at_harvest(served_model):
     cfg, model, params = served_model
     prompts = _prompts(cfg, _LENS, seed=6)
     server = Server(model, params, ServerConfig(
-        num_slots=2, page_size=4, max_seq_len=24, prefill_bucket=8,
+        num_slots=2, page_size=4, max_seq_len=24, prefill_chunk=8,
         async_depth=2))
     reqs = {}
     for p, g in zip(prompts, _GENS):
@@ -213,7 +210,7 @@ def test_batched_prefill_compile_count_bounded(served_model):
     cfg, model, params = served_model
     prompts = _prompts(cfg, (3, 5, 6, 7, 9, 11, 4, 8), seed=7)
     server = Server(model, params, ServerConfig(
-        num_slots=4, page_size=4, max_seq_len=24, prefill_bucket=8,
+        num_slots=4, page_size=4, max_seq_len=24,
         prefill_chunk=4, prefill_batch=True, async_depth=1))
     for p in prompts:
         server.submit(p, max_new_tokens=3)
@@ -260,7 +257,7 @@ def test_async_depth_inert_under_spec(served_model):
 
     def run(depth):
         server = Server(model, params, ServerConfig(
-            num_slots=2, page_size=4, max_seq_len=48, prefill_bucket=16,
+            num_slots=2, page_size=4, max_seq_len=48, prefill_chunk=16,
             async_depth=depth), spec=SpecConfig(k=3, ngram_n=3))
         req = server.submit(prompt, max_new_tokens=8)
         return server.run()[req.rid].out_tokens

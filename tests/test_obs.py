@@ -220,8 +220,8 @@ def test_json_tracer_chrome_schema_golden(tmp_path):
     t.end(PID_REQUESTS, 0, "queued")
     t.instant(PID_REQUESTS, 0, "admitted", slot=1)
     t.begin(PID_REQUESTS, 0, "prefill_chunk", start=0, tokens=4)
-    t.begin(PID_DEVICE, DEVICE_TID, "prefill_full", tokens=4)
-    t.end(PID_DEVICE, DEVICE_TID, "prefill_full")
+    t.begin(PID_DEVICE, DEVICE_TID, "prefill_chunk.dispatch", tokens=4)
+    t.end(PID_DEVICE, DEVICE_TID, "prefill_chunk.dispatch")
     t.end(PID_REQUESTS, 0, "prefill_chunk")
     t.begin(PID_REQUESTS, 0, "decode")
     t.instant(PID_REQUESTS, 0, "finished", finish_reason="length")
@@ -335,7 +335,7 @@ _GENS = (6, 3, 8, 5)
 def _run_server(model, params, prompts, *, tracer=None, spec=None,
                 prefill_chunk=4):
     server = Server(model, params, ServerConfig(
-        num_slots=2, page_size=4, max_seq_len=24, prefill_bucket=8,
+        num_slots=2, page_size=4, max_seq_len=24,
         prefill_chunk=prefill_chunk,
     ), tracer=tracer, spec=spec)
     reqs = [server.submit(p, max_new_tokens=g)
@@ -390,7 +390,7 @@ def test_profiler_trace_holds_the_step_spans(served_model, tmp_path):
 
     cfg, model, params = served_model
     server = Server(model, params, ServerConfig(
-        num_slots=2, page_size=4, max_seq_len=24, prefill_bucket=8,
+        num_slots=2, page_size=4, max_seq_len=24,
         prefill_chunk=4))
     eng, seen = server.engine, []
     dispatch_decode = eng.dispatch_decode
@@ -500,7 +500,7 @@ def test_reset_clears_spec_counters_and_metrics(served_model):
         motif = list(rng.integers(0, cfg.vocab_size, size=3 + i))
         prompts.append(motif * 3)
     server = Server(model, params, ServerConfig(
-        num_slots=2, page_size=4, max_seq_len=48, prefill_bucket=16,
+        num_slots=2, page_size=4, max_seq_len=48, prefill_chunk=16,
     ), spec=SpecConfig(k=3), tracer=JsonTracer())
     for p in prompts:
         server.submit(p, max_new_tokens=8)
@@ -554,7 +554,7 @@ def test_scheduler_queue_gauges(served_model):
     cfg, model, params = served_model
     prompts = _prompts(cfg, (5, 6, 7))
     server = Server(model, params, ServerConfig(
-        num_slots=1, page_size=4, max_seq_len=16, prefill_bucket=8,
+        num_slots=1, page_size=4, max_seq_len=16, prefill_chunk=8,
     ))
     for p in prompts:
         server.submit(p, max_new_tokens=6)

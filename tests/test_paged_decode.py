@@ -294,7 +294,7 @@ def test_server_greedy_parity_with_kernel_backend():
     g = np.random.default_rng(7)
     prompts = [list(g.integers(0, cfg.vocab_size, size=n)) for n in (5, 9, 3)]
     server = Server(model, params, ServerConfig(
-        num_slots=2, page_size=4, max_seq_len=24, prefill_bucket=8,
+        num_slots=2, page_size=4, max_seq_len=24, prefill_chunk=8,
     ), backend="pallas_interpret")
     reqs = [server.submit(p, max_new_tokens=6) for p in prompts]
     results = server.run()

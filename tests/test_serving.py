@@ -6,6 +6,7 @@ ring-buffer path produces for that prompt alone — scheduling and cache
 layout are not allowed to change results.
 """
 import dataclasses
+import functools
 import random
 
 import jax
@@ -164,7 +165,7 @@ def test_continuous_matches_static_greedy(served_model):
     gens = (6, 3, 8, 5)
     prompts = _prompts(cfg, lens)
     server = Server(model, params, ServerConfig(
-        num_slots=2, page_size=4, max_seq_len=24, prefill_bucket=8,
+        num_slots=2, page_size=4, max_seq_len=24, prefill_chunk=8,
     ))
     reqs = [server.submit(p, max_new_tokens=g) for p, g in zip(prompts, gens)]
     results = server.run()
@@ -191,7 +192,7 @@ def test_continuous_matches_static_greedy_sliding_window():
     params = model.init(jax.random.PRNGKey(2))
     prompts = _prompts(cfg, (14, 10), seed=9)
     server = Server(model, params, ServerConfig(
-        num_slots=2, page_size=4, max_seq_len=24, prefill_bucket=8,
+        num_slots=2, page_size=4, max_seq_len=24, prefill_chunk=8,
     ))
     reqs = [server.submit(p, max_new_tokens=8) for p in prompts]
     results = server.run()
@@ -207,7 +208,7 @@ def test_slot_recycling_and_stats(served_model):
     cfg, model, params = served_model
     prompts = _prompts(cfg, (4, 6, 5, 7, 4), seed=3)
     server = Server(model, params, ServerConfig(
-        num_slots=2, page_size=4, max_seq_len=16, prefill_bucket=8,
+        num_slots=2, page_size=4, max_seq_len=16, prefill_chunk=8,
     ))
     for p in prompts:
         server.submit(p, max_new_tokens=4)
@@ -225,7 +226,7 @@ def test_eos_finish_and_streaming(served_model):
     cfg, model, params = served_model
     (prompt,) = _prompts(cfg, (6,), seed=5)
     cfgs = ServerConfig(num_slots=1, page_size=4, max_seq_len=16,
-                        prefill_bucket=8)
+                        prefill_chunk=8)
     server = Server(model, params, cfgs)
     req = server.submit(prompt, max_new_tokens=5)
     first_tokens = server.run()[req.rid].out_tokens
@@ -248,7 +249,7 @@ def test_fp8_kv_pages_match_fp8_ring(served_model):
     model8 = build(cfg8)
     (prompt,) = _prompts(cfg, (9,), seed=7)
     server = Server(model8, params, ServerConfig(
-        num_slots=2, page_size=4, max_seq_len=24, prefill_bucket=16,
+        num_slots=2, page_size=4, max_seq_len=24, prefill_chunk=16,
     ))
     req = server.submit(prompt, max_new_tokens=6)
     out = server.run()[req.rid].out_tokens
@@ -273,8 +274,9 @@ def test_fp8_vs_bf16_kv_logit_tolerance(served_model):
         toks = jnp.zeros((1, 8), jnp.int32).at[0].set(jnp.asarray(prompt))
         page_row = jnp.asarray([1, 2, 3, 0], jnp.int32)  # page 3: decode room
         logits, pools = m.prefill_cb(
-            params, toks, pools, page_row, jnp.int32(0), jnp.int32(0),
-            jnp.int32(8), page_size=4)
+            params, toks, pools, page_row[None], jnp.zeros((1,), jnp.int32),
+            jnp.zeros((1,), jnp.int32), jnp.full((1,), 8, jnp.int32),
+            page_size=4)
         tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
         table = jnp.zeros((1, 4), jnp.int32).at[0].set(page_row)
         lens = jnp.full((1,), 8, jnp.int32)
@@ -308,7 +310,7 @@ def test_server_rejects_unsupported_arch():
 def test_warmup_then_reset_leaves_clean_state(served_model):
     cfg, model, params = served_model
     server = Server(model, params, ServerConfig(
-        num_slots=2, page_size=4, max_seq_len=16, prefill_bucket=8,
+        num_slots=2, page_size=4, max_seq_len=16, prefill_chunk=8,
     ))
     server.warmup([5, 9])
     assert server.stats.decode_steps == 0 and not server.results
@@ -325,7 +327,7 @@ def _cb_vs_static(arch, *, prefill_chunk, lens=(5, 11, 7, 9),
     params = model.init(jax.random.PRNGKey(0))
     prompts = _prompts(cfg, lens, seed=seed)
     server = Server(model, params, ServerConfig(
-        num_slots=num_slots, page_size=4, max_seq_len=24, prefill_bucket=8,
+        num_slots=num_slots, page_size=4, max_seq_len=24,
         prefill_chunk=prefill_chunk,
     ))
     reqs = [server.submit(p, max_new_tokens=g) for p, g in zip(prompts, gens)]
@@ -357,24 +359,24 @@ def test_continuous_matches_static_greedy_xlstm_chunked():
     assert server.scheduler.worst_pages(24) == 0
 
 
-def test_chunked_prefill_matches_unchunked_attention():
-    """Chunked and whole-prompt prefill must produce identical greedy
-    tokens on an attention arch (fp32: gather-through-pool is exact)."""
-    cfg = _fp32(get_config("granite-3-8b", smoke=True))
-    model = build(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+@pytest.mark.parametrize("chunk", [1, 4, 16], ids=["one", "page", "wide"])
+def test_chunked_prefill_matches_unchunked_attention(served_model, chunk):
+    """Every chunk width — one token, one page, and wider than every
+    prompt — gives the static ring path's greedy tokens on an attention
+    arch (fp32: gather-through-pool is exact)."""
+    cfg, model, params = served_model
     prompts = _prompts(cfg, (5, 13, 9), seed=21)
-
-    def run(chunk):
-        server = Server(model, params, ServerConfig(
-            num_slots=2, page_size=4, max_seq_len=24, prefill_bucket=8,
-            prefill_chunk=chunk,
-        ))
-        reqs = [server.submit(p, max_new_tokens=6) for p in prompts]
-        results = server.run()
-        return [results[r.rid].out_tokens for r in reqs]
-
-    assert run(None) == run(4)
+    server = Server(model, params, ServerConfig(
+        num_slots=2, page_size=4, max_seq_len=24, prefill_chunk=chunk,
+    ))
+    reqs = [server.submit(p, max_new_tokens=6) for p in prompts]
+    results = server.run()
+    for p, r in zip(prompts, reqs):
+        ref, _ = generate_static(
+            model, params, {"tokens": jnp.asarray([p], jnp.int32)},
+            max_new_tokens=6,
+        )
+        assert results[r.rid].out_tokens == list(ref[0]), f"prompt len {len(p)}"
 
 
 def _state_rows(tree, slot):
@@ -394,13 +396,13 @@ def test_masked_prefill_state_matches_full_scan(arch):
     """Property: per-slot recurrent state after chunked paged prefill ==
     the full-scan state of the static path, including a recycle-then-reuse
     of the same slot (start == 0 must reset the row by construction)."""
-    from repro.training import make_paged_serve_steps
+    from repro.serving.steps import make_paged_serve_steps
 
     cfg = _fp32(get_config(arch, smoke=True))
     model = build(cfg)
     params = model.init(jax.random.PRNGKey(3))
     page_size, chunk, n_slots = 4, 4, 2
-    _, prefill_chunk, _, _ = make_paged_serve_steps(model, page_size=page_size)
+    prefill_chunk, _, _ = make_paged_serve_steps(model, page_size=page_size)
     pools = model.init_state_store(n_slots, 16, page_size)
     page_rows = {0: jnp.asarray([1, 2, 3, 4, 0, 0], jnp.int32),
                  1: jnp.asarray([5, 6, 7, 8, 0, 0], jnp.int32)}
@@ -451,6 +453,81 @@ def test_masked_prefill_state_matches_full_scan(arch):
                                    np.asarray(want, np.float32), **tol)
 
 
+def _live_leaves(pools, page_size):
+    """Every pools leaf, KV pools without the null page (the write target
+    of every pad token, whose content is never read back)."""
+    out = []
+    for path, leaf in jax.tree_util.tree_flatten_with_path(pools)[0]:
+        keys = [getattr(k, "key", None) for k in path]
+        if "kp" in keys or "vp" in keys:
+            leaf = leaf[..., page_size:, :, :]  # token axis is -3
+        out.append(np.asarray(leaf))
+    return out
+
+
+@pytest.mark.parametrize("arch", ["granite-3-8b", "gemma2-2b",
+                                  "recurrentgemma-2b"])
+def test_prefill_rows_match_single_rows_and_verify(arch):
+    """The slot mappings keep rows apart. P rows of ``prefill_cb`` commit,
+    bit for bit, the pools of P one-row calls and the logits of P calls
+    in which only that row is live; ``verify_cb(commit=True)`` over the
+    same rows gives those logits at each row's last valid position. Rows
+    continue committed history (past gemma2's 16-token window) or start
+    fresh, in a slot order that is not the table's. A one-row call's
+    logits come from a matrix-vector product, which XLA's CPU backend
+    sums in another order, so they agree to float32 rounding."""
+    cfg = _fp32(get_config(arch, smoke=True))
+    model = build(cfg)
+    params = model.init(jax.random.PRNGKey(5))
+    page_size, pps, t, n = 4, 8, 6, 3
+    table = jnp.arange(1, n * pps + 1, dtype=jnp.int32).reshape(n, pps)
+    prefill = jax.jit(functools.partial(model.prefill_cb, page_size=page_size))
+    verify = jax.jit(functools.partial(model.verify_cb, page_size=page_size,
+                                       commit=True))
+    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
+
+    history = [12, 0, 17]
+    lengths = [6, 3, 4]
+    prompts = _prompts(cfg, [h + t for h in history], seed=8)
+    pools = model.init_state_store(n, n * pps + 1, page_size)
+    for slot, h in enumerate(history):
+        if h:
+            _, pools = prefill(params, i32([prompts[slot][:h]]), pools,
+                               table[slot:slot + 1], i32([slot]), i32([0]),
+                               i32([h]))
+    toks = np.zeros((n, t), np.int32)
+    for slot, (h, m) in enumerate(zip(history, lengths)):
+        toks[slot, :m] = prompts[slot][h:h + m]
+
+    order = np.asarray([2, 0, 1])
+    args = (i32(toks[order]), pools, table[order], i32(order),
+            i32(history)[order], i32(lengths)[order])
+    rows_logits, rows_pools = prefill(params, *args)
+
+    single_pools = pools
+    for r, slot in enumerate(order):
+        logits, _ = prefill(params, *args, np.arange(n) == r)
+        np.testing.assert_array_equal(np.asarray(logits[r]),
+                                      np.asarray(rows_logits[r]))
+        logits, single_pools = prefill(
+            params, i32(toks[slot:slot + 1]), single_pools,
+            table[slot:slot + 1], i32([slot]), i32([history[slot]]),
+            i32([lengths[slot]]))
+        np.testing.assert_allclose(np.asarray(logits[0]),
+                                   np.asarray(rows_logits[r]),
+                                   rtol=1e-6, atol=1e-6)
+    for got, want in zip(_live_leaves(rows_pools, page_size),
+                         _live_leaves(single_pools, page_size)):
+        np.testing.assert_array_equal(got, want)
+
+    v_logits, _ = verify(params, i32(toks), pools, table, i32(history),
+                         i32(lengths), jnp.ones((n,), bool))
+    for r, slot in enumerate(order):
+        np.testing.assert_array_equal(
+            np.asarray(v_logits[slot, lengths[slot] - 1]),
+            np.asarray(rows_logits[r]))
+
+
 # -- reservation from the actual pool layout ----------------------------------
 
 def test_zero_page_reservation_admits_by_slots_only():
@@ -487,7 +564,7 @@ def test_window_page_recycling_bounds_held_pages():
     model = build(cfg)
     params = model.init(jax.random.PRNGKey(1))
     server = Server(model, params, ServerConfig(
-        num_slots=1, page_size=4, max_seq_len=64, prefill_bucket=8,
+        num_slots=1, page_size=4, max_seq_len=64,
         prefill_chunk=8,
     ))
     (prompt,) = _prompts(cfg, (30,), seed=2)
@@ -511,16 +588,17 @@ def test_window_page_recycling_bounds_held_pages():
 
 
 def test_unchunked_windowed_long_prompt_never_overdraws():
-    """Whole-prompt prefill on an all-windowed arch allocates every prompt
-    page at once, so the reservation must cover the full prompt (the
-    windowed cap applies only under chunked prefill) — a long prompt must
-    neither raise OutOfPagesError nor change results."""
+    """A chunk wider than the window on an all-windowed arch: each chunk
+    allocates all of its pages before recycling runs, so the reservation
+    (window + chunk + slack) must cover it — a long prompt must neither
+    raise OutOfPagesError nor change results."""
     cfg = _fp32(get_config("recurrentgemma-2b", smoke=True))  # window 16
     model = build(cfg)
     params = model.init(jax.random.PRNGKey(1))
     server = Server(model, params, ServerConfig(
-        num_slots=1, page_size=4, max_seq_len=64, prefill_bucket=8,
+        num_slots=1, page_size=4, max_seq_len=64, prefill_chunk=24,
     ))
+    assert server.engine.reserve_tokens_cap() < 64  # windowed, not full
     (prompt,) = _prompts(cfg, (40,), seed=4)
     req = server.submit(prompt, max_new_tokens=8)
     results = server.run()
@@ -532,8 +610,9 @@ def test_unchunked_windowed_long_prompt_never_overdraws():
 
 
 def test_prefill_chunk_of_one_token(served_model):
-    """The degenerate chunk size (1 token per step) must still route
-    through the chunked-prefill attention branch and keep greedy parity."""
+    """The degenerate chunk size (1 token per step) takes the decode form
+    of the slot mappings with prefill's state-row commits, and keeps
+    greedy parity."""
     cfg, model, params = served_model
     (prompt,) = _prompts(cfg, (5,), seed=17)
     server = Server(model, params, ServerConfig(
@@ -657,7 +736,7 @@ def test_rid_counter_is_per_scheduler():
 def test_rid_counter_resets_with_server(served_model):
     cfg, model, params = served_model
     server = Server(model, params, ServerConfig(
-        num_slots=1, page_size=4, max_seq_len=16, prefill_bucket=8,
+        num_slots=1, page_size=4, max_seq_len=16, prefill_chunk=8,
     ))
     assert server.submit([1, 2, 3], max_new_tokens=2).rid == 0
     server.reset()

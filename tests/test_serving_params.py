@@ -19,7 +19,8 @@ from repro.models import build
 from repro.obs import WeightPrep, weight_prep_tally
 from repro.optim import AdamW
 from repro.serving import Server, ServerConfig, sample_logits
-from repro.training import TrainState, make_paged_serve_steps, make_train_step
+from repro.serving.steps import make_paged_serve_steps
+from repro.training import TrainState, make_train_step
 
 E4M3 = jnp.float8_e4m3fn
 VOCAB = 300  # pads to 384 on the lane
@@ -46,7 +47,7 @@ def tiny():
 def _serve_greedy(model, params):
     """Prefill one prompt per slot, then greedy decode steps over both:
     every step's logits."""
-    prefill, _, _, decode = make_paged_serve_steps(model, page_size=PAGE)
+    prefill, _, decode = make_paged_serve_steps(model, page_size=PAGE)
     prefill, decode = jax.jit(prefill), jax.jit(decode)
     pools = model.init_state_store(SLOTS, SLOTS * PAGES_PER_SLOT + 1, PAGE)
     table = np.arange(1, SLOTS * PAGES_PER_SLOT + 1, dtype=np.int32).reshape(
